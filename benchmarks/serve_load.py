@@ -30,19 +30,15 @@ import json
 import random
 import sys
 
-from repro.compat import ensure_jax_compat
+import jax
 
-ensure_jax_compat()
-
-import jax  # noqa: E402
-
-from repro.configs import get_config, reduced  # noqa: E402
-from repro.configs.base import ShapeConfig  # noqa: E402
-from repro.core.plan import MemoryPlan  # noqa: E402
-from repro.launch.mesh import make_local_mesh  # noqa: E402
-from repro.models import kvcache as KV  # noqa: E402
-from repro.models import model as M  # noqa: E402
-from repro.serve import DecodeEngine, Request, choose_paging  # noqa: E402
+from repro.configs import get_config, reduced
+from repro.configs.base import ShapeConfig
+from repro.core.plan import MemoryPlan
+from repro.launch.mesh import make_local_mesh
+from repro.models import kvcache as KV
+from repro.models import model as M
+from repro.serve import DecodeEngine, Request, choose_paging
 
 MODES = ("replay", "whole", "chunked")
 

@@ -27,25 +27,21 @@ import json
 import os
 import sys
 
-from repro.compat import ensure_jax_compat
+import jax
 
-ensure_jax_compat()
-
-import jax  # noqa: E402
-
-from repro import obs  # noqa: E402
-from repro.configs import ARCHS, reduced  # noqa: E402
-from repro.configs.base import ShapeConfig  # noqa: E402
-from repro.core import build_workload  # noqa: E402
-from repro.core.hardware import LOCAL_CPU_HW, MeshSpec  # noqa: E402
-from repro.core.plan import MemoryPlan  # noqa: E402
-from repro.data.pipeline import SyntheticTokenPipeline  # noqa: E402
-from repro.launch.mesh import make_local_mesh  # noqa: E402
-from repro.models import model as M  # noqa: E402
-from repro.models import kvcache as KV  # noqa: E402
-from repro.serve import DecodeEngine, Request, choose_paging  # noqa: E402
-from repro.train import step_builder as SB  # noqa: E402
-from repro.train.loop import LoopConfig, train_loop  # noqa: E402
+from repro import obs
+from repro.configs import ARCHS, reduced
+from repro.configs.base import ShapeConfig
+from repro.core import build_workload
+from repro.core.hardware import LOCAL_CPU_HW, MeshSpec
+from repro.core.plan import MemoryPlan
+from repro.data.pipeline import SyntheticTokenPipeline
+from repro.launch.mesh import make_local_mesh
+from repro.models import model as M
+from repro.models import kvcache as KV
+from repro.serve import DecodeEngine, Request, choose_paging
+from repro.train import step_builder as SB
+from repro.train.loop import LoopConfig, train_loop
 
 # the 8-layer toy: small enough for ~1 s CPU steps, big enough that the cost
 # model's CPU pricing and the live-array watermark both land well inside the

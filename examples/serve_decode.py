@@ -13,16 +13,12 @@ import time
 
 import jax
 
-from repro.compat import ensure_jax_compat
-
-ensure_jax_compat()
-
-from repro.configs import get_config, reduced  # noqa: E402
-from repro.configs.base import ShapeConfig  # noqa: E402
-from repro.core.plan import MemoryPlan  # noqa: E402
-from repro.launch.mesh import make_local_mesh  # noqa: E402
-from repro.models import model as M  # noqa: E402
-from repro.serve import DecodeEngine, Request  # noqa: E402
+from repro.configs import get_config, reduced
+from repro.configs.base import ShapeConfig
+from repro.core.plan import MemoryPlan
+from repro.launch.mesh import make_local_mesh
+from repro.models import model as M
+from repro.serve import DecodeEngine, Request
 
 B, PROMPT, GEN = 4, 32, 16
 

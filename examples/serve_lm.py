@@ -21,18 +21,14 @@ import argparse
 import jax
 import jax.numpy as jnp
 
-from repro.compat import ensure_jax_compat
-
-ensure_jax_compat()
-
-from repro import obs  # noqa: E402
-from repro.configs import get_config, reduced  # noqa: E402
-from repro.configs.base import ShapeConfig  # noqa: E402
-from repro.core.plan import MemoryPlan  # noqa: E402
-from repro.launch.mesh import make_local_mesh  # noqa: E402
-from repro.models import kvcache as KV  # noqa: E402
-from repro.models import model as M  # noqa: E402
-from repro.serve import DecodeEngine, Request, choose_paging  # noqa: E402
+from repro import obs
+from repro.configs import get_config, reduced
+from repro.configs.base import ShapeConfig
+from repro.core.plan import MemoryPlan
+from repro.launch.mesh import make_local_mesh
+from repro.models import kvcache as KV
+from repro.models import model as M
+from repro.serve import DecodeEngine, Request, choose_paging
 
 
 def build_requests(n: int, vocab: int, max_new: int) -> list[Request]:

@@ -1,151 +1,47 @@
-"""Version-compatibility shims for the jax API surface this repo targets.
+"""Backend facts the rest of the package branches on, in one place.
 
-The codebase is written against the explicit-sharding era jax API:
-``jax.sharding.AxisType`` and ``jax.make_mesh(..., axis_types=...)``. Older
-jaxlib builds (<= 0.4.x) predate both. ``ensure_jax_compat()`` installs
-lightweight forwarders so every call site works unchanged on either version:
+The package targets the installed jax (0.9): ``jax.shard_map``,
+``jax.sharding.AxisType`` and the differentiable
+``jax.lax.optimization_barrier`` are used directly. What remains here are
+the two backend decisions that must never silently hide the device:
 
-  * ``jax.sharding.AxisType`` — a stand-in enum when missing (the values are
-    only ever passed back into ``make_mesh``, never inspected);
-  * ``jax.make_mesh`` — wrapped to accept-and-drop ``axis_types`` when the
-    underlying implementation does not know the kwarg (pre-explicit-sharding
-    meshes are Auto on every axis, which is exactly what the repo requests).
-
-Idempotent and cheap; called from ``repro.dist`` import and the test
-conftest so any entry point that builds a mesh is covered.
+  * ``pallas_interpret_required`` — Pallas kernels run compiled on TPU and
+    in interpret mode on the CPU test backend; any other backend is an
+    error, not a quiet fallback;
+  * ``host_memory_kind`` — the host memory space host-placed state lives
+    in; a platform without one raises instead of degrading host placement
+    to device residence.
 """
 from __future__ import annotations
 
-import enum
 import functools
-import inspect
 
 import jax
 
 
-def ensure_jax_compat() -> None:
-    if not hasattr(jax.sharding, "AxisType"):
-        class AxisType(enum.Enum):
-            Auto = "auto"
-            Explicit = "explicit"
-            Manual = "manual"
-
-        jax.sharding.AxisType = AxisType
-
-    # follow_wrapped=False: functools.wraps sets __wrapped__, and a followed
-    # signature would never show the shim's added kwarg — breaking idempotency
-    sig = inspect.signature(jax.make_mesh, follow_wrapped=False)
-    if "axis_types" not in sig.parameters:
-        orig = jax.make_mesh
-
-        @functools.wraps(orig)
-        def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-            del axis_types  # pre-explicit-sharding meshes are Auto everywhere
-            return orig(axis_shapes, axis_names, devices=devices)
-
-        jax.make_mesh = make_mesh
-
-
-@functools.lru_cache(maxsize=None)
-def _barrier_is_differentiable() -> bool:
-    try:
-        jax.grad(lambda x: jax.lax.optimization_barrier((x,))[0])(1.0)
-        return True
-    except NotImplementedError:
-        return False
-
-
-@jax.custom_vjp
-def _barrier(tree):
-    return jax.lax.optimization_barrier(tree)
-
-
-def _barrier_fwd(tree):
-    return jax.lax.optimization_barrier(tree), None
-
-
-def _barrier_bwd(_, ct):
-    return (jax.lax.optimization_barrier(ct),)
-
-
-_barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-
-def optimization_barrier(tree):
-    """``jax.lax.optimization_barrier`` that is differentiable everywhere.
-
-    Older jax releases ship the primitive without an AD rule; the barrier is
-    semantically an identity, so a custom-vjp wrapper (barrier on the
-    cotangents too, matching the newer built-in rule) restores gradients.
-    """
-    if _barrier_is_differentiable():
-        return jax.lax.optimization_barrier(tree)
-    return _barrier(tree)
-
-
-try:  # moved to jax.shard_map in newer releases
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-
-def shard_map(f, mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across jax versions.
-
-    ``check`` maps to the replication-checker flag, which jax has renamed
-    (``check_rep`` -> ``check_vma``); callers that emit gather-based
-    all-reduces (dist/collectives.manual_*) pass False because the checker
-    cannot see that all_gather + identical local math yields replicated
-    outputs.
-    """
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=check)
-    except TypeError:  # pragma: no cover - newer jax renamed the flag
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=check)
-
-
-@functools.lru_cache(maxsize=None)
-def pallas_supported() -> bool:
-    """Can this process run the Pallas kernels at all?
-
-    True when ``jax.experimental.pallas`` imports and either the backend
-    compiles Pallas natively (TPU/GPU) or interpret mode can execute the
-    kernel bodies op-by-op (the CPU fallback our tests use — bit-identical
-    math, no Mosaic). False on builds without Pallas, in which case the
-    ``repro.kernels`` package routes every request to the pure-jnp reference
-    implementations instead of crashing."""
-    try:
-        import jax.experimental.pallas as pl  # noqa: F401
-    except Exception:  # pragma: no cover - jaxlib built without pallas
-        return False
-    return True
-
-
 @functools.lru_cache(maxsize=None)
 def pallas_interpret_required() -> bool:
-    """True when Pallas must run in interpret mode (no kernel compiler for
-    this backend — i.e. anything but TPU/GPU)."""
-    try:
-        return jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
-    except Exception:  # pragma: no cover - backend init can fail headless
+    """True on the CPU backend (Pallas interpret mode), False on TPU
+    (compiled kernels). Raises on any other backend."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
         return True
+    raise RuntimeError(f"no Pallas mode for backend {backend!r}: "
+                       "kernels compile on tpu and interpret on cpu")
 
 
-def host_memory_kind(mesh) -> str | None:
-    """The best host-side memory kind the mesh's devices support.
+def host_memory_kind(mesh) -> str:
+    """The host memory kind the mesh's devices address.
 
-    TPU/GPU expose ``pinned_host``; the CPU backend only ``unpinned_host``
-    (which still exercises every placement/fetch code path in tests). Returns
-    None when the platform has no addressable host memory space at all, in
-    which case host placement degrades to device residence.
+    TPU exposes ``pinned_host``; the CPU backend only ``unpinned_host``
+    (which still exercises every placement/fetch code path in tests).
     """
-    try:
-        kinds = {m.kind for m in mesh.devices.flat[0].addressable_memories()}
-    except Exception:
-        return None
+    kinds = {m.kind for m in mesh.devices.flat[0].addressable_memories()}
     for kind in ("pinned_host", "unpinned_host"):
         if kind in kinds:
             return kind
-    return None
+    raise RuntimeError(
+        f"{mesh.devices.flat[0]} has no host memory space (kinds: "
+        f"{sorted(kinds)}); host-placed state cannot be realised")

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import os
 
 import jax
@@ -37,6 +38,8 @@ from repro.core.chunks import ChunkInfo, chunk_inventory
 from repro.core.hardware import HardwareSpec, MeshSpec
 from repro.core.plan import MemoryPlan
 from repro.core.profiler import BlockProfile, profile_superblock
+
+_LOG = logging.getLogger(__name__)
 
 ADAM_FLOPS_PER_PARAM = 12.0  # fused Adam: ~12 flops/param (exp avgs + update)
 FP32 = 4
@@ -129,9 +132,10 @@ def load_wire_calibration(path: str | None = None) -> dict | None:
     analytic DEFAULT_WIRE_FACTORS/DEFAULT_EF_RESIDUAL_FACTOR value at lookup
     time, so an old-format JSON never KeyErrors the search.
     With ``path=None`` resolves ``$REPRO_WIRE_CALIBRATION``, then the packaged
-    ``src/repro/core/wire_calibration.json``. Returns the active per-backend
-    entry (matched against ``jax.default_backend()``, falling back to the
-    first entry) or None when no file exists.
+    ``src/repro/core/wire_calibration.json``. Returns the entry of
+    ``jax.default_backend()``, or None when no file exists or it has no
+    entry for this backend — the analytic defaults then price every
+    pipeline (logged: a factor fit on another backend is never used).
     """
     global _CALIBRATION, _CALIBRATION_LOADED
     _CALIBRATION_LOADED = True
@@ -143,12 +147,11 @@ def load_wire_calibration(path: str | None = None) -> dict | None:
         return None
     with open(path) as f:
         data = json.load(f)
-    backends = data.get("backends", {})
-    try:
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - backend init can fail headless
-        backend = None
-    entry = backends.get(backend) or (next(iter(backends.values())) if backends else None)
+    backend = jax.default_backend()
+    entry = data.get("backends", {}).get(backend)
+    if entry is None:
+        _LOG.warning("no wire calibration for backend %r in %s; using the "
+                     "analytic default factors", backend, path)
     _CALIBRATION = entry
     return entry
 
@@ -537,30 +540,15 @@ LAX_REBUILD_CACHE_PASSES = 3.0
 KERNEL_CACHE_PASSES = 2.0
 
 
-def decode_kernel_active() -> bool:
-    """Does the decode step route through the fused paged-attention kernel?
-
-    Mirrors serve/paging.PagedKV's auto-resolution (kernel path engages
-    when the kernels package dispatches to Pallas); host-sharded fetch
-    plans keep the lax pipeline and price with ``kernel=False``."""
-    try:
-        from repro.kernels import pallas_kernels_active
-    except Exception:  # pragma: no cover - kernels package import failure
-        return False
-    return pallas_kernels_active()
-
-
 def paged_cache_read_bytes(cfg: ModelConfig, shape: ShapeConfig,
                            mesh: MeshSpec, spec,
-                           kernel: bool | None = None) -> float:
+                           kernel: bool = True) -> float:
     """Per-device HBM bytes one paged decode step reads from the KV cache:
     the resident hot rings plus each attention layer's per-step cache
     stream at the kernel-aware pass count (see LAX_REBUILD_CACHE_PASSES /
     KERNEL_CACHE_PASSES)."""
     from repro.core.serve_plan import _paged_parts_per_device
 
-    if kernel is None:
-        kernel = decode_kernel_active()
     parts = _paged_parts_per_device(cfg, shape, mesh, spec)
     if kernel:
         passes = KERNEL_CACHE_PASSES * wire_factor("serve", "paged_attn")
@@ -571,7 +559,7 @@ def paged_cache_read_bytes(cfg: ModelConfig, shape: ShapeConfig,
 
 def t_decode_compute(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec,
                      hw: HardwareSpec, spec=None,
-                     kernel: bool | None = None) -> float:
+                     kernel: bool = True) -> float:
     """One decode step's compute window per device: the active-parameter
     matmuls against the weight + cache read bandwidth floor.
 
@@ -579,8 +567,8 @@ def t_decode_compute(cfg: ModelConfig, shape: ShapeConfig, mesh: MeshSpec,
     (``paged_cache_read_bytes``): the fused paged-attention kernel streams
     2 passes over each layer's cache working set where the lax rebuild
     takes 3, so the modeled decode window shrinks when the kernel is
-    active. ``kernel=None`` auto-resolves via ``decode_kernel_active()``;
-    without a spec the resident-cache pricing is unchanged."""
+    active (the default, as in ``serve/paging.PagedKV``); without a spec the
+    resident-cache pricing is unchanged."""
     b_loc = shape.global_batch / mesh.zero_degree
     flops = 2.0 * cfg.active_param_count() * b_loc / mesh.tp_degree
     weights_dev = sum(c.param_bytes for c in chunk_inventory(cfg)) / mesh.tp_degree

@@ -116,6 +116,32 @@ LOCAL_CPU_HW = HardwareSpec(
 
 HARDWARE = {h.name: h for h in (TPU_V5E, RTX_3090, A100_80G)}
 
+# Accelerators the planner can target, keyed by ``Device.device_kind`` as
+# jax reports it. TPU_V5E's peaks: Google Cloud documentation, "TPU v5e".
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def host_memory_bytes() -> float:
+    """This host's physical memory (``MemTotal`` of /proc/meminfo), in bytes."""
+    with open("/proc/meminfo") as f:
+        for line in f:
+            if line.startswith("MemTotal:"):
+                return float(line.split()[1]) * 1024
+    raise RuntimeError("no MemTotal line in /proc/meminfo")
+
+
+def hardware_for_device(device) -> HardwareSpec:
+    """The spec of ``device``'s accelerator, with this host's memory.
+
+    An unknown ``device_kind`` is an error: planning a chip against another
+    chip's constants would produce a plan that does not fit or wastes it.
+    """
+    spec = DEVICE_KINDS.get(device.device_kind)
+    if spec is None:
+        raise ValueError(f"no HardwareSpec for device kind {device.device_kind!r} "
+                         f"(known: {sorted(DEVICE_KINDS)})")
+    return dataclasses.replace(spec, host_mem_bytes=host_memory_bytes())
+
 
 @dataclasses.dataclass(frozen=True)
 class MeshSpec:

@@ -8,8 +8,4 @@ MemoryPlan's placement (persist | hbm | host) via sharding memory kinds.
 ``repro.dist.collectives`` provides the wire-format-compressed gradient
 synchronization primitives (bf16 cast, int8 + error feedback).
 """
-from repro.compat import ensure_jax_compat
-
-ensure_jax_compat()
-
-from repro.dist import collectives, sharding  # noqa: E402,F401
+from repro.dist import collectives, sharding  # noqa: F401

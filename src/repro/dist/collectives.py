@@ -64,8 +64,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import optimization_barrier, shard_map
-
 
 def _mesh_size(mesh) -> int:
     return math.prod(mesh.devices.shape)
@@ -79,7 +77,7 @@ def _replica_mean(x: jax.Array, mesh, axis_names) -> jax.Array:
     def mean(v):
         return (jax.lax.psum(v.astype(jnp.float32), axes) / n).astype(x.dtype)
 
-    return shard_map(mean, mesh=mesh, in_specs=P(), out_specs=P())(x)
+    return jax.shard_map(mean, mesh=mesh, in_specs=P(), out_specs=P())(x)
 
 
 # ---------------------------------------------------------------------------
@@ -208,9 +206,9 @@ def _chunk(x: jax.Array, dim: int, z: int) -> jax.Array:
 
 
 # Fused quantize/pack dispatch for the reduce-scatter wire path. Tri-state:
-# None (default) auto-resolves to the Pallas kernel when the kernels package
-# dispatches to Pallas; True/False force the path (differential tests drive
-# both sides, and the fidelity/bench harnesses pin it for labeled rows).
+# None (default) resolves to the Pallas kernel; True/False force the path
+# (differential tests drive both sides, and the fidelity/bench harnesses pin
+# it for labeled rows).
 _FUSED_QUANT: bool | None = None
 
 
@@ -222,11 +220,7 @@ def set_fused_quant(enabled: bool | None) -> None:
 
 
 def fused_quant_enabled() -> bool:
-    if _FUSED_QUANT is not None:
-        return _FUSED_QUANT
-    from repro.kernels import pallas_kernels_active
-
-    return pallas_kernels_active()
+    return True if _FUSED_QUANT is None else _FUSED_QUANT
 
 
 def manual_reduce_scatter(x: jax.Array, axis_names, dim: int,
@@ -351,13 +345,12 @@ def gather_param_lazy(w: jax.Array, err, axis_names, dim: int,
     ``optimization_barrier``-paired with the anchor value, so XLA may issue
     this chunk's all-gather as soon as the anchor exists — during the
     previous chunk's matmuls — but never earlier (pipeline depth stays
-    bounded). The barrier is differentiable (compat.optimization_barrier
-    barriers cotangents through a custom_vjp where needed), so the
-    reduce-scatter transpose above is untouched.
+    bounded). The barrier is differentiable (its transpose barriers the
+    cotangents), so the reduce-scatter transpose above is untouched.
     """
     g = _gather_param_lazy(tuple(_names(axis_names)), int(dim), compress, w, err)
     if anchor is not None:
-        g, _ = optimization_barrier((g, anchor))
+        g, _ = jax.lax.optimization_barrier((g, anchor))
     return g
 
 

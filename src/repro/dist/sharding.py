@@ -101,9 +101,7 @@ def sharding_for(
     assert placement in ("persist", "hbm", "host"), placement
     spec = _spec(d, mesh, placement, dp_only)
     if placement == "host":
-        kind = host_memory_kind(mesh)
-        if kind is not None:
-            return NamedSharding(mesh, spec, memory_kind=kind)
+        return NamedSharding(mesh, spec, memory_kind=host_memory_kind(mesh))
     return NamedSharding(mesh, spec)
 
 

@@ -1,71 +1,23 @@
-"""Capability-gated kernel dispatch (ROADMAP: "wire the Pallas paths into
-the step builder behind a capability check").
+"""Pallas kernels and their package-level entry points.
 
-Consumers request fused ops from *this package* — never from ``ops.py``
-directly — so the request is always safe:
-
-  * ``repro.compat.pallas_supported()`` — Pallas imports and can execute
-    (compiled on TPU/GPU, interpret mode elsewhere; ``ops.py`` picks via
-    ``compat.pallas_interpret_required``): route to the Pallas wrappers;
-  * otherwise (jaxlib built without Pallas): route to the pure-jnp oracles
-    in ``ref.py``, which are the allclose targets the kernels are tested
-    against — same math, no crash.
-
-``optim/adam.py`` reaches its fused update through here, which is what lets
-``AdamConfig(use_fused_kernel=True)`` run on CPU CI (interpret mode) and on
-kernel-less builds (reference path) without special-casing the step builder.
+Consumers request fused ops from *this package*; ``ops.py`` runs each
+kernel compiled on TPU and in interpret mode on the CPU test backend
+(``compat.pallas_interpret_required``), and ``ref.py`` holds the pure-jnp
+oracles the kernels are tested against.
 
 ``fused_adam_update``, ``decode_paged_attention``, and ``fused_quantize_ef``
 are re-exported at package level: their names do not collide with a
 submodule. ``flash_attention`` / ``rmsnorm`` keep their submodule import
-paths (``repro.kernels.ops`` applies the same capability gating) — binding
-same-named functions on the package would shadow the
-``repro.kernels.flash_attention`` / ``repro.kernels.rmsnorm`` modules for
-``import … as`` style imports (which is also why the decode kernel exports
-as ``decode_paged_attention``, not ``paged_attention``).
-
-``pallas_kernels_active()`` is the capability probe call sites gate *path
-selection* on (serve/paging.PagedKV's kernel-vs-lax split, the collectives'
-fused-vs-three-op quantize, cost-model pricing): True means the package
-routes to real Pallas wrappers rather than the ref fallbacks.
+paths (``repro.kernels.ops``) — binding same-named functions on the package
+would shadow the ``repro.kernels.flash_attention`` /
+``repro.kernels.rmsnorm`` modules for ``import … as`` style imports (which
+is also why the decode kernel exports as ``decode_paged_attention``, not
+``paged_attention``).
 """
 from __future__ import annotations
 
-from repro.compat import pallas_supported
-
-
-def pallas_kernels_active() -> bool:
-    """True when this package dispatches to Pallas kernels (compiled or
-    interpret), False when it routes to the ref.py oracles."""
-    return pallas_supported()
-
-
-if pallas_supported():
-    from repro.kernels.ops import (  # noqa: F401
-        decode_paged_attention,
-        fused_adam_update,
-        fused_quantize_ef,
-    )
-else:  # pragma: no cover - exercised only on pallas-less jaxlib builds
-
-    def fused_adam_update(p, g, master, m, v, *, lr, b1, b2, eps,
-                          weight_decay, bc1, bc2):
-        """Signature-compatible reference fallback (see optim/adam.py)."""
-        from repro.kernels.ref import fused_adam_ref
-
-        return fused_adam_ref(p, g, master, m, v, lr=lr, b1=b1, b2=b2,
-                              eps=eps, weight_decay=weight_decay,
-                              bc1=bc1, bc2=bc2)
-
-    def decode_paged_attention(q, k_hot, v_hot, k_cold, v_cold, sel, mask,
-                               *, n_hot):
-        """Signature-compatible reference fallback (see serve/paging.py)."""
-        from repro.kernels.ref import paged_attention_ref
-
-        return paged_attention_ref(q, k_hot, v_hot, k_cold, v_cold, sel, mask)
-
-    def fused_quantize_ef(ch, me):
-        """Signature-compatible reference fallback (see dist/collectives.py)."""
-        from repro.kernels.ref import fused_quantize_ef_ref
-
-        return fused_quantize_ef_ref(ch, me)
+from repro.kernels.ops import (  # noqa: F401
+    decode_paged_attention,
+    fused_adam_update,
+    fused_quantize_ef,
+)

@@ -23,8 +23,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -149,7 +147,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),  # m (running max)
             pltpu.VMEM((block_q, 1), jnp.float32),  # l (running denom)
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

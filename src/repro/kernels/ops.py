@@ -1,13 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels run compiled; everywhere else (this CPU container) they
-run in interpret mode, which executes the kernel body op-by-op — bit-for-bit
-the same math, so tests validate the kernel logic against the ref.py oracles
-without TPU hardware.
+On TPU the kernels run compiled; on the CPU test backend they run in
+interpret mode, which executes the kernel body op-by-op — bit-for-bit the
+same math, so tests validate the kernel logic against the ref.py oracles
+without TPU hardware. Any other backend raises
+(``compat.pallas_interpret_required``, resolved once per process).
 
-The interpret decision is resolved once per process (``interpret_mode``):
-it depends only on the backend, which jax fixes at first use, so consulting
-``compat.pallas_interpret_required`` on every kernel call was pure overhead.
 ``assert_ref_agreement`` is the one shared kernel-vs-oracle structure
 checker (dtype + shape over arbitrary output pytrees) used by the kernel
 tests and ``benchmarks/kernel_bench.py`` — per-op copies of the same
@@ -18,26 +16,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pallas_interpret_required
+from repro.compat import pallas_interpret_required as interpret_mode
 from repro.kernels import flash_attention as _flash
 from repro.kernels import fused_adam as _fa
 from repro.kernels import fused_quant as _fq
 from repro.kernels import paged_attention as _pa
 from repro.kernels import rmsnorm as _rn
-
-_INTERPRET: bool | None = None
-
-
-def interpret_mode() -> bool:
-    """Process-wide interpret decision, resolved on first kernel call.
-
-    Interpret mode covers every backend without a Pallas compiler (CPU CI
-    included); the capability probe lives in repro.compat.
-    """
-    global _INTERPRET
-    if _INTERPRET is None:
-        _INTERPRET = pallas_interpret_required()
-    return _INTERPRET
 
 
 def assert_ref_agreement(kernel_out, ref_out) -> None:

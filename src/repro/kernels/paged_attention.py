@@ -22,7 +22,8 @@ Block layout per (batch*kv-head, page) grid step::
       q        (1, G, hd)    fixed block, G = Hq // Hkv query heads
       k_hot    (1, P, hd)    ring page  j % n_hot   ─┐ per-row select
       k_cold   (1, P, hd)    cold page  j           ─┘ (sel block)
-      sel,mask (1, P)        residency + additive NEG_INF decode mask
+      sel      (1, P, 1)     per-row residency (int32, rows on sublanes)
+      mask     (1, 1, P)     additive NEG_INF decode mask (lanes, as logits)
       scratch  logits (G, S) fp32, v (S, hd) fp32   accumulated across pages
       out      (1, G, hd)    written on the final page
 
@@ -57,23 +58,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# CompilerParams was renamed across jax releases (same fields)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(q_ref, kh_ref, kc_ref, vh_ref, vc_ref, sel_ref, mask_ref,
             o_ref, logits_ref, v_ref, *, n_pages: int, hd: int):
     j = pl.program_id(1)
     psz = kh_ref.shape[1]
     # per-row residency select: True -> hot ring holds the canonical value
-    sel = sel_ref[0][:, None]
+    sel = sel_ref[0] != 0  # (P, 1)
     k = jnp.where(sel, kh_ref[0], kc_ref[0]).astype(jnp.float32)
     v = jnp.where(sel, vh_ref[0], vc_ref[0]).astype(jnp.float32)
     # same scaling op sequence as _masked_decode_attn: fp32 cast, / sqrt(hd)
     qf = q_ref[0].astype(jnp.float32) / jnp.sqrt(jnp.float32(hd))
     logits = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    logits_ref[:, pl.ds(j * psz, psz)] = logits + mask_ref[0][None, :]
+    logits_ref[:, pl.ds(j * psz, psz)] = logits + mask_ref[0]
     v_ref[pl.ds(j * psz, psz), :] = v
 
     @pl.when(j == n_pages - 1)
@@ -131,8 +129,8 @@ def paged_attention(
             pl.BlockSpec((1, psz, hd), lambda h, j: (h, j, 0)),
             pl.BlockSpec((1, psz, hd), lambda h, j: (h, j % n_hot, 0)),
             pl.BlockSpec((1, psz, hd), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, psz), lambda h, j: (h // hkv, j)),
-            pl.BlockSpec((1, psz), lambda h, j: (h // hkv, j)),
+            pl.BlockSpec((1, psz, 1), lambda h, j: (h // hkv, j, 0)),
+            pl.BlockSpec((1, 1, psz), lambda h, j: (h // hkv, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, g, hd), lambda h, j: (h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, hd), q.dtype),
@@ -140,8 +138,9 @@ def paged_attention(
             pltpu.VMEM((g, s_kv), jnp.float32),
             pltpu.VMEM((s_kv, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, fold(k_hot), fold(k_cold), fold(v_hot), fold(v_cold), sel, mask)
+    )(qf, fold(k_hot), fold(k_cold), fold(v_hot), fold(v_cold),
+      sel.astype(jnp.int32)[:, :, None], mask[:, None, :])
     return out.reshape(b, hkv, g, hd).reshape(b, 1, hq, hd)

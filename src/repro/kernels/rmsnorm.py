@@ -15,7 +15,8 @@ from jax.experimental import pallas as pl
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)  # (block_rows, D)
     ms = jnp.mean(x * x, axis=-1, keepdims=True)
-    o_ref[...] = (x * jax.lax.rsqrt(ms + eps)).astype(o_ref.dtype) * s_ref[...]
+    y = x * jax.lax.rsqrt(ms + eps) * s_ref[...].astype(jnp.float32)
+    o_ref[...] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "eps", "interpret"))

@@ -1,8 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes and record memory/cost/collective evidence.
+
+A compile-only tool: it pins itself to 512 virtual CPU devices (appending to
+``XLA_FLAGS``, overriding ``JAX_PLATFORMS``) before jax initializes, so it
+runs the same on a machine that has a TPU.
 
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-405b --shape train_4k
@@ -10,6 +11,12 @@ the production meshes and record memory/cost/collective evidence.
 Writes one JSON line per cell to reports/dryrun_cells.jsonl (append; completed
 cells are skipped on re-run, so a crashed sweep resumes).
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512").strip()
+
 import argparse
 import json
 import traceback

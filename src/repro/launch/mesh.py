@@ -8,10 +8,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import ensure_jax_compat
 from repro.core.hardware import MULTI_POD, SINGLE_POD, MeshSpec
-
-ensure_jax_compat()  # API shims only — no backend/device initialization
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -4,29 +4,117 @@
         --steps 200 --batch 8 --seq 256 --ckpt-dir /tmp/run1
 
 Selects the architecture, runs the ProTrain automatic memory-management
-search for the *local* hardware (CPU devices here; TPU v5e constants when
---target-hw tpu-v5e is passed for plan inspection), builds the plan-realized
-train step, and runs the fault-tolerant loop with checkpointing + auto-resume.
+search, builds the plan-realized train step, and runs the fault-tolerant
+loop with checkpointing + auto-resume.
+
+On an accelerator the search plans against the spec of the chip
+``jax.devices()[0].device_kind`` names (``--target-hw`` overrides it), the
+plan is realised as searched — host chunks included — and its step is
+compiled before training (``fit_plan``). On the CPU backend the plan is
+searched for a TPU v5e (or ``--target-hw``) for inspection, and without
+``--target-hw`` its chunks are parked on device: host offload means nothing
+on a CPU.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
+import os
+import re
+import time
+from pathlib import Path
+from typing import Callable
 
 import jax
 
 from repro.configs import get_config, reduced
 from repro.configs.base import ShapeConfig
 from repro.core import TPU_V5E, build_workload, search
-from repro.core.hardware import HARDWARE, MeshSpec
+from repro.core.autotuner import SearchResult
+from repro.core.hardware import HARDWARE, HardwareSpec, MeshSpec, hardware_for_device
 from repro.core.plan import MemoryPlan, fully_resident_plan
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.data.pipeline import SyntheticTokenPipeline
 from repro.launch.mesh import make_local_mesh
 from repro.optim.adam import AdamConfig, cosine_schedule
 from repro.train.loop import LoopConfig, train_loop
-from repro.train.step_builder import build_train_step
+from repro.train.step_builder import StepArtifacts, build_train_step
+
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache(root: Path = CHECKOUT_ROOT) -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (jax reads it itself), else in
+    ``.jax_cache/`` at the checkout root — a fixed path, because the path is
+    part of the cache key. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(root / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+_OVER_HBM = re.compile(r"Exceeded hbm capacity by ([\d.]+)([KMGT]?)")
+
+
+def hbm_overshoot(err: Exception) -> float | None:
+    """Bytes by which the TPU compiler found a program over device memory,
+    parsed from its RESOURCE_EXHAUSTED message; None for any other error."""
+    m = _OVER_HBM.search(str(err))
+    if m is None:
+        return None
+    return float(m.group(1)) * 1024 ** " KMGT".index(m.group(2) or " ")
+
+
+@dataclasses.dataclass
+class FitResult:
+    """A searched plan whose step the chip's compiler accepted."""
+
+    search: SearchResult
+    art: StepArtifacts
+    compiled: jax.stages.Compiled
+    compile_s: float  # seconds to lower + compile the accepted step
+    capacity_bytes: float  # planning capacity the accepted search used
+    misses: list[tuple[float, float]]  # (modeled peak, compiler overshoot) per refusal
+
+
+def fit_plan(cfg, shape: ShapeConfig, mesh, hw: HardwareSpec,
+             build: Callable[[MemoryPlan], StepArtifacts], *,
+             max_tries: int = 4, log: Callable[[str], None] = print) -> FitResult:
+    """Search the plan and compile its step for the devices of ``mesh``.
+
+    The cost model's peak is a prediction; the compiler's verdict is the
+    fact. When the compiler refuses the step for exceeding device memory
+    by X bytes, the search runs again with its capacity lowered by X, so
+    the plan that runs is still the planner's fastest one — under the
+    capacity the compiler can actually deliver.
+    """
+    mspec = MeshSpec(tuple(mesh.devices.shape), tuple(mesh.axis_names))
+    w = build_workload(cfg, shape, mspec, hw)
+    cap = hw.capacity_bytes()
+    misses: list[tuple[float, float]] = []
+    for _ in range(max_tries):
+        res = search(w, capacity_bytes=cap, sp="auto")
+        art = build(res.plan)
+        t0 = time.perf_counter()
+        try:
+            compiled = art.lower().compile()
+        except jax.errors.JaxRuntimeError as e:
+            over = hbm_overshoot(e)
+            if over is None:
+                raise
+            misses.append((res.memory.peak, over))
+            log(f"[fit] {res.plan.describe()}: modeled peak "
+                f"{res.memory.peak / 1e9:.3f} GB, compiler needs {over / 1e9:.3f} GB "
+                f"more than the device has; searching again at capacity "
+                f"{(cap - over) / 1e9:.3f} GB")
+            cap -= over
+            continue
+        return FitResult(res, art, compiled, time.perf_counter() - t0, cap, misses)
+    raise RuntimeError(f"no plan fits after {max_tries} compiles: {misses}")
 
 
 def main(argv=None):
@@ -41,36 +129,53 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true",
                     help="train the reduced (smoke-scale) variant of the arch")
     ap.add_argument("--target-hw", default=None, choices=[None, *HARDWARE],
-                    help="plan against this hardware spec instead of local")
+                    help="plan against this hardware spec instead of the local chip")
     ap.add_argument("--plan", default="auto", choices=["auto", "resident", "fsdp"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     mesh = make_local_mesh()
     n_dev = len(jax.devices())
-    mspec = MeshSpec(tuple(mesh.devices.shape), tuple(mesh.axis_names))
+    on_cpu = jax.default_backend() == "cpu"
+    adam = AdamConfig(lr=args.lr)
+    lr_schedule = cosine_schedule(args.lr, warmup=min(20, args.steps // 10 + 1),
+                                  total=args.steps)
+
+    def build(plan: MemoryPlan) -> StepArtifacts:
+        return build_train_step(cfg, plan, mesh, shape, adam=adam,
+                                lr_schedule=lr_schedule)
 
     from repro.core.chunks import chunk_inventory
     from repro.models.model import num_repeats
 
     nc = len(chunk_inventory(cfg))
     nb = num_repeats(cfg)
-    if args.plan == "auto":
+    art = None
+    if args.plan == "auto" and not on_cpu:
+        hw = (HARDWARE[args.target_hw] if args.target_hw
+              else hardware_for_device(jax.devices()[0]))
+        fit = fit_plan(cfg, shape, mesh, hw, build)
+        art, plan = fit.art, fit.art.plan
+        print(f"[train] searched plan: {plan.describe()} (modeled "
+              f"t_iter={fit.search.runtime.t_iteration:.3f}s on {hw.name}, "
+              f"compiled in {fit.compile_s:.1f}s)")
+    elif args.plan == "auto":
         hw = HARDWARE[args.target_hw] if args.target_hw else TPU_V5E
-        w = build_workload(cfg, shape, mspec, hw)
-        res = search(w, sp="auto")
+        mspec = MeshSpec(tuple(mesh.devices.shape), tuple(mesh.axis_names))
+        res = search(build_workload(cfg, shape, mspec, hw), sp="auto")
         plan = res.plan
         print(f"[train] searched plan: {plan.describe()} "
               f"(modeled t_iter={res.runtime.t_iteration:.3f}s on {hw.name})")
         if args.target_hw is None:
-            # local CPU run: memory-kind offload is pointless; keep the block
-            # policies but park chunks on device
-            plan = dataclasses.replace(plan, n_host=0, n_persist=plan.n_chunks
-                                       - 0, n_buffer=0)
+            # CPU run: memory-kind offload means nothing here; keep the
+            # block policies but park every chunk on device
+            plan = dataclasses.replace(plan, n_host=0, n_persist=plan.n_chunks,
+                                       n_buffer=0)
     elif args.plan == "fsdp":
         plan = MemoryPlan(n_chunks=nc, n_blocks=nb, n_checkpoint=nb)
     else:
@@ -78,12 +183,7 @@ def main(argv=None):
     print(f"[train] arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
           f"devices={n_dev} plan={plan.describe()}")
 
-    art = build_train_step(
-        cfg, plan, mesh, shape,
-        adam=AdamConfig(lr=args.lr),
-        lr_schedule=cosine_schedule(args.lr, warmup=min(20, args.steps // 10 + 1),
-                                    total=args.steps),
-    )
+    art = art or build(plan)
     pipe = SyntheticTokenPipeline(cfg, shape, seed=args.seed)
     mgr = CheckpointManager(args.ckpt_dir, keep=2) if args.ckpt_dir else None
     res = train_loop(

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from repro.compat import optimization_barrier
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import mamba2 as M2
@@ -170,9 +169,9 @@ def set_act_quant_kernel(enabled: bool | None) -> None:
 def act_quant_kernel_active() -> bool:
     if _ACT_QUANT_KERNEL is not None:
         return _ACT_QUANT_KERNEL
-    from repro.compat import pallas_interpret_required, pallas_supported
+    from repro.compat import pallas_interpret_required
 
-    return pallas_supported() and not pallas_interpret_required()
+    return not pallas_interpret_required()
 
 
 def _quantize_rows(x2d: jax.Array):
@@ -283,7 +282,7 @@ def gather_weights(params, specs=None):
     # it XLA commutes slice-of-stack with all-gather and hoists the gather of
     # the whole stacked run out of the loop — materializing every layer's
     # weights at once (the exact pattern chunk-wise gathering must avoid).
-    params = optimization_barrier(params)
+    params = jax.lax.optimization_barrier(params)
     return jax.tree.map(
         lambda w, s: checkpoint_name(w if s is None else jax.device_put(w, s), GATHERED_W),
         params,

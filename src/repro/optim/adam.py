@@ -64,9 +64,6 @@ def _update_leaf(p, g, master, m, v, *, cfg: AdamConfig, lr, bc1, bc2, fused: bo
         m = jax.device_put(m, d_shard)
         v = jax.device_put(v, d_shard)
     if fused and host is None:
-        # package-level dispatch: Pallas when the backend supports it (compat
-        # .pallas_supported), pure-jnp reference otherwise — requesting the
-        # fused kernel is always safe, never a crash on kernel-less backends
         from repro.kernels import fused_adam_update
 
         return fused_adam_update(

@@ -33,11 +33,13 @@ HBM-resident cache or the host-paged one.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Iterable, Iterator
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -138,18 +140,41 @@ class EngineReport:
         }
 
 
-def _zero_slots(cache, mask: jax.Array):
-    """Zero every cache leaf's rows for slots where ``mask`` is True.
+_HOST_KINDS = ("pinned_host", "unpinned_host")
+
+
+def _zero_slots(dev, host, mask: jax.Array, *, host_sh):
+    """Zero the rows of slots where ``mask`` is True in every cache leaf.
 
     All decode-cache leaves carry the batch dim at axis 1 — (R, B, ...) —
-    for both resident and paged layouts.
+    for both resident and paged layouts. ``dev`` are the device-resident
+    leaves, zeroed by one select each. ``host`` are host-resident cold
+    pages: a select there would need all of it in device memory (jax
+    rejects mixing memory spaces in one op, and the cold store may exceed
+    HBM), so each masked slot gets a device zero block written into the
+    host buffer and the result is pinned back to ``host_sh``.
     """
 
-    def one(x):
+    def zero_dev(x):
         m = mask.reshape((1, -1) + (1,) * (x.ndim - 2))
         return jnp.where(m, jnp.zeros((), x.dtype), x)
 
-    return jax.tree.map(one, cache)
+    def zero_host(x, sh):
+        # the store and one slot's zero block, both in host memory
+        x = jax.device_put(x, sh)
+        spec = tuple(sh.spec) + (None,) * (x.ndim - len(sh.spec))
+        z = jax.device_put(
+            jnp.zeros(x.shape[:1] + (1,) + x.shape[2:], x.dtype),
+            NamedSharding(sh.mesh, P(spec[0], None, *spec[2:]),
+                          memory_kind=sh.memory_kind))
+        for b in range(x.shape[1]):
+            x = jax.lax.cond(
+                mask[b],
+                lambda x, b=b: jax.lax.dynamic_update_slice_in_dim(x, z, b, axis=1),
+                lambda x: x, x)
+        return x
+
+    return [zero_dev(x) for x in dev], [zero_host(x, s) for x, s in zip(host, host_sh)]
 
 
 class DecodeEngine:
@@ -217,14 +242,15 @@ class DecodeEngine:
                                      paging, shardings=cache_sh)
         self.state = {"params": params, "cache": cache}
         self._step = jax.jit(self.art.fn, donate_argnums=(0,))
-        # out_shardings keep the cold pages in host memory through the reset:
-        # without them the jitted zeroing would materialize the whole cold
-        # store in device memory (a full h2d+d2h round trip per admission,
-        # and an OOM whenever the cold store exceeds HBM — the exact regime
-        # paging exists for; invisible on CPU CI where host == device)
-        self._reset = jax.jit(_zero_slots, donate_argnums=(0,),
-                              out_shardings=cache_sh)
-        self._cache_sh = cache_sh
+        # the reset donates the device leaves only: the CPU backend cannot
+        # alias a donated host buffer into the re-pinned output
+        sh_flat, self._cache_def = jax.tree.flatten(cache_sh)
+        self._host_idx = [i for i, s in enumerate(sh_flat)
+                          if s.memory_kind in _HOST_KINDS]
+        self._zero = jax.jit(
+            functools.partial(_zero_slots,
+                              host_sh=[sh_flat[i] for i in self._host_idx]),
+            donate_argnums=(0,))
 
         cache_len = KVC.cache_len(cfg, shape.seq_len)
         if admission != "replay":
@@ -327,6 +353,16 @@ class DecodeEngine:
             self.state, _ = self._prefill(self.state, pb)
         self.state["cache"] = self._reset(self.state["cache"],
                                           jnp.zeros((bsz,), bool))
+
+    def _reset(self, cache, mask: jax.Array):
+        """Zero the cache rows of the slots in ``mask`` (see _zero_slots)."""
+        leaves = self._cache_def.flatten_up_to(cache)
+        host = set(self._host_idx)
+        dev, hst = self._zero([x for i, x in enumerate(leaves) if i not in host],
+                              [leaves[i] for i in self._host_idx], mask)
+        dev, hst = iter(dev), iter(hst)
+        return self._cache_def.unflatten(
+            [next(hst) if i in host else next(dev) for i in range(len(leaves))])
 
     def submit(self, requests: Iterable[Request]) -> None:
         """Queue requests; admission happens on subsequent ticks."""

@@ -44,7 +44,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import optimization_barrier
 from repro.configs.base import ModelConfig
 from repro.models import kvcache as KV
 
@@ -203,9 +202,7 @@ class PagedKV:
         self.fetch_sharding = fetch_sharding
         self.flush = flush
         if use_kernel is None:
-            from repro.kernels import pallas_kernels_active
-
-            use_kernel = pallas_kernels_active() and fetch_sharding is None
+            use_kernel = fetch_sharding is None
         self.use_kernel = use_kernel
 
     # -- page residency -----------------------------------------------------
@@ -279,7 +276,7 @@ class PagedKV:
                 # double buffer: this fetch may start only once the
                 # page-before-last materialized (≤ 2 transfers in flight),
                 # and the barrier pins the pipeline inside the repeat scan
-                cold_rows, _ = optimization_barrier((cold_rows, pages[-2]))
+                cold_rows, _ = jax.lax.optimization_barrier((cold_rows, pages[-2]))
             fetch = self.fetch_sharding
 
             def from_cold(h, c, _sh=fetch):

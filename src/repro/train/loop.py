@@ -51,9 +51,11 @@ class LoopResult:
     steps_run: int
     final_step: int
     losses: list[float]
+    step_times: list[float]  # seconds per finite-loss step, state ready
     resumed_from: int | None
     straggler_events: int
     nan_skips: int
+    state: Any = None  # the train state after the last step
 
 
 def train_loop(
@@ -77,7 +79,7 @@ def train_loop(
     nan_c = reg.counter("train.nan_skips")
     straggler_c = reg.counter("train.straggler_events")
 
-    jfn = jax.jit(step_artifacts.fn, donate_argnums=(0,))
+    jfn = step_artifacts.jit()
     plan = getattr(step_artifacts, "plan", None)
     grad_compress = getattr(plan, "grad_compress", "none") if plan is not None else "none"
     if grad_compress != "none":
@@ -140,11 +142,12 @@ def train_loop(
     step = start_step
     try:
         while step < loop_cfg.total_steps:
-            batch = pipeline.next_sync()
+            batch = jax.device_put(pipeline.next_sync(), step_artifacts.batch_shardings)
             t0 = time.perf_counter()
             with tracer.span("train.step", step=step):
                 new_state, metrics = jfn(state, batch)
-                loss = float(metrics["loss"])  # device sync: the step is done
+                jax.block_until_ready(new_state)  # the update too, not just the loss
+                loss = float(metrics["loss"])
             dt = time.perf_counter() - t0
             step_time_h.observe(dt)
             steps_c.inc()
@@ -219,7 +222,9 @@ def train_loop(
         steps_run=step - start_step,
         final_step=step,
         losses=losses,
+        step_times=step_times,
         resumed_from=resumed_from,
         straggler_events=straggler_events,
         nan_skips=nan_skips,
+        state=state,
     )

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import optimization_barrier
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.plan import MemoryPlan
 from repro.dist import collectives as COLL
@@ -104,9 +103,23 @@ class StepArtifacts:
     runs: list[RunLayout]
     init: Callable | None = None  # (key) -> state, concrete (small models)
 
+    def jit(self, donate: bool = True):
+        """The step under ``jax.jit`` with the state's shardings pinned on
+        both sides: the state a step returns then hits the program compiled
+        for the state ``init`` made, and one compile serves the whole run."""
+        if jax.default_backend() == "cpu" and any(
+                s.memory_kind != "device" for s in jax.tree.leaves(self.state_shardings)):
+            # The CPU backend returns host-placed outputs in device memory
+            # and aborts when a donated host buffer is aliased into an
+            # output, so host-placed state steps there unpinned and
+            # undonated. On TPU the state stays in pinned_host, donated.
+            return jax.jit(self.fn)
+        return jax.jit(self.fn, in_shardings=(self.state_shardings, self.batch_shardings),
+                       out_shardings=(self.state_shardings, None),
+                       donate_argnums=(0,) if donate else ())
+
     def lower(self, donate: bool = True):
-        jfn = jax.jit(self.fn, donate_argnums=(0,) if donate else ())
-        return jfn.lower(self.state_specs, self.batch_specs)
+        return self.jit(donate).lower(self.state_specs, self.batch_specs)
 
 
 def _opt_placement(placement: str, plan: MemoryPlan) -> str:
@@ -226,13 +239,14 @@ def build_train_step(
         )
     ]
 
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P()))
     state_specs = {
         "params": SH.tree_specs(p_defs, p_shard),
         "opt": {
             **{k: SH.tree_specs(opt_defs[k], opt_shard[k]) for k in ("master", "m", "v")},
-            "count": jax.ShapeDtypeStruct((), jnp.int32),
+            "count": scalar,
         },
-        "step": jax.ShapeDtypeStruct((), jnp.int32),
+        "step": scalar,
     }
     state_shardings = {
         "params": p_shard,
@@ -358,7 +372,7 @@ def build_train_step(
                 keys = [k for k in ("final_norm", "head")
                         if fetch_specs.get(k) is not None and k in fparams]
                 if keys:
-                    bundled, _ = optimization_barrier(
+                    bundled, _ = jax.lax.optimization_barrier(
                         ({k: fparams[k] for k in keys}, fparams["embed"]))
                     fparams = {**fparams, **bundled}
             h, aux = M.forward(
@@ -546,19 +560,28 @@ def build_train_step(
                                 host_plan=host_plan_flat, repin=True)
 
     def init(key):
-        flat_defs = p_defs
         from repro.models.layers import init_tree
 
-        params = init_tree(flat_defs, key)
-        params = jax.tree.map(jax.device_put, params, p_shard)
-        opt = OPT.init_opt_state(params)
+        params = jax.tree.map(jax.device_put, init_tree(p_defs, key), p_shard)
+
+        # Each optimizer leaf is made and placed on its own, so device
+        # memory never holds the whole fp32 state at once (host-placed
+        # states exceed HBM), and as a fresh buffer: an fp32 param's
+        # ``astype`` would alias its master copy and break donation.
+        def master(p, s):
+            return jax.device_put(jnp.array(p, jnp.float32, copy=True), s)
+
+        def zeros(p, s):
+            return jax.device_put(jnp.zeros(p.shape, jnp.float32), s)
+
         opt = {
-            "master": jax.tree.map(jax.device_put, opt["master"], opt_shard["master"]),
-            "m": jax.tree.map(jax.device_put, opt["m"], opt_shard["m"]),
-            "v": jax.tree.map(jax.device_put, opt["v"], opt_shard["v"]),
-            "count": opt["count"],
+            "master": jax.tree.map(master, params, opt_shard["master"]),
+            "m": jax.tree.map(zeros, params, opt_shard["m"]),
+            "v": jax.tree.map(zeros, params, opt_shard["v"]),
+            "count": jax.device_put(jnp.zeros((), jnp.int32), state_shardings["opt"]["count"]),
         }
-        state = {"params": params, "opt": opt, "step": jnp.zeros((), jnp.int32)}
+        state = {"params": params, "opt": opt,
+                 "step": jax.device_put(jnp.zeros((), jnp.int32), state_shardings["step"])}
         if compress == "int8_ef":
             # zeros matching state_specs["ef"] — param-shaped replicated for
             # the xla path, stacked per-device for manual (see above)
@@ -567,9 +590,7 @@ def build_train_step(
                 state_specs["ef"],
                 is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
             )
-        # identical constants (m/v zeros, step/count scalars) may share device
-        # buffers, which breaks donation ("donate the same buffer twice")
-        return jax.tree.map(lambda x: x.copy(), state)
+        return state
 
     return StepArtifacts(
         fn=step_fn,
@@ -663,7 +684,6 @@ def _serve_cache_layout(cfg: ModelConfig, plan: MemoryPlan, mesh,
     ba = SH.batch_axes(mesh)
     tp = "model" if "model" in mesh.axis_names else None
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    host_kind = host_memory_kind(mesh)
 
     def fits(dim: int, axes) -> bool:
         if axes is None:
@@ -690,8 +710,8 @@ def _serve_cache_layout(cfg: ModelConfig, plan: MemoryPlan, mesh,
             if not fits(shp[2], seq_ax):
                 seq_ax = tp if fits(shp[2], tp) else None
             spec = P(None, batch_ax, seq_ax, None, None)
-            if name in ("k_cold", "v_cold") and host_kind is not None:
-                return NamedSharding(mesh, spec, memory_kind=host_kind)
+            if name in ("k_cold", "v_cold"):
+                return NamedSharding(mesh, spec, memory_kind=host_memory_kind(mesh))
             return NamedSharding(mesh, spec)
         if name == "conv":  # (R, B, K, conv_dim)
             ch = tp if fits(shp[3], tp) else None

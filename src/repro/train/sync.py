@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import optimization_barrier, shard_map
 from repro.dist import collectives as COLL
 from repro.dist import sharding as SH
 from repro.models.layers import ParamDef
@@ -110,7 +109,7 @@ def accumulate_grads(micro_grad, batch, microbatch, ef, acc_like, pin=None,
             # from serve/paging).
             g_acc = jax.tree.map(lambda a, b: a + b, g_acc, g_pend)
             g_pend = jax.tree.map(lambda a, b: b.astype(a.dtype), g_acc, g)
-            g_pend, _ = optimization_barrier((g_pend, g_acc))
+            g_pend, _ = jax.lax.optimization_barrier((g_pend, g_acc))
             return (g_acc, g_pend, l_acc + tot, ef_c), None
 
         (g_acc, g_pend, total, ef), _ = jax.lax.scan(
@@ -446,8 +445,8 @@ class ManualSync:
         # replication check off: the checker cannot see that a gather-based
         # all-reduce (all_gather + identical local mean) yields replicated
         # outputs; replication holds by construction (dist/collectives.py)
-        return shard_map(body, self.mesh, in_specs=(state_ps, batch_ps),
-                         out_specs=(state_ps, metrics_ps), check=False)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=(state_ps, batch_ps),
+                             out_specs=(state_ps, metrics_ps), check_vma=False)
 
 
 def make_strategy(plan, mesh, tp_degree: int) -> XlaSync | ManualSync:

@@ -1,24 +1,5 @@
-"""Suite-wide setup: jax API compat + hypothesis fallback.
-
-Must run before any test module imports, hence conftest:
-  * ensure_jax_compat() lets the explicit-sharding call sites
-    (jax.sharding.AxisType, make_mesh(axis_types=...)) run on older jaxlib;
-  * when the declared `hypothesis` test dep is absent (hermetic CI image),
-    the deterministic stub in repro.testing keeps the property suites running
-    instead of failing collection.
-"""
+"""Suite-wide setup: make ``src/`` importable before any test module."""
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
-
-from repro.compat import ensure_jax_compat
-
-ensure_jax_compat()
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from repro.testing import hypothesis_stub
-
-    hypothesis_stub.install()

@@ -46,7 +46,6 @@ import math
 
 import pytest
 
-from repro.compat import shard_map
 from repro.dist.collectives import (
     manual_bf16_reduce_scatter,
     manual_int8_ef_reduce_scatter,
@@ -64,8 +63,8 @@ def data_mesh():
 
 def _run_rs(fn, local_inputs, in_specs, out_specs):
     mesh = data_mesh()
-    return jax.jit(shard_map(fn, mesh, in_specs=in_specs, out_specs=out_specs,
-                             check=False))(*local_inputs)
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))(*local_inputs)
 
 
 @needs_multi
@@ -156,10 +155,10 @@ def test_int8_reduce_scatter_ef_feedback_reduces_own_shard_error():
         return s[None], ne[None]
 
     mesh = data_mesh()
-    f = jax.jit(shard_map(
-        body, mesh,
+    f = jax.jit(jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P("data", None, None), P("data", None, None)),
-        out_specs=(P("data", None, None), P("data", None, None)), check=False))
+        out_specs=(P("data", None, None), P("data", None, None)), check_vma=False))
     _, err1 = f(g, err)
     _, err2 = f(g, err1)
     # the EF invariant: transmitted + residual == input + prior residual for

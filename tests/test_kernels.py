@@ -126,18 +126,24 @@ def test_rmsnorm_matches_ref(shape, dtype, atol):
 
 
 # ---------------------------------------------------------------------------
-# capability-gated package dispatch (repro.kernels behind compat probes)
+# package dispatch: the Pallas mode follows the backend
 # ---------------------------------------------------------------------------
-def test_package_dispatch_routes_through_capability_check():
-    """The public ops come from the package, gated on pallas_supported():
-    requesting the fused kernel must work on every backend (interpret mode
-    here on CPU) and agree with the reference oracle."""
+def test_package_dispatch_routes_through_capability_check(monkeypatch):
+    """The public ops come from the package and run the Pallas kernel:
+    interpret mode on the CPU backend, compiled on TPU, and an error on any
+    other backend (never a silent reference fallback). The fused kernel
+    agrees with the reference oracle."""
     from repro import compat
     from repro import kernels as K
 
-    assert isinstance(compat.pallas_supported(), bool)
-    if jax.default_backend() == "cpu":
-        assert compat.pallas_interpret_required()
+    probe = compat.pallas_interpret_required.__wrapped__
+    assert probe() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert probe() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no Pallas mode"):
+        probe()
+    monkeypatch.undo()
     p = rand(KEY, (64, 32), jnp.bfloat16)
     g = rand(jax.random.fold_in(KEY, 1), (64, 32), jnp.bfloat16)
     master = p.astype(jnp.float32)

@@ -289,6 +289,23 @@ def test_calibration_roundtrip(tmp_path):
         CM.reset_wire_calibration()
 
 
+def test_calibration_for_another_backend_is_not_used(tmp_path, monkeypatch):
+    """A file with entries for other backends only leaves the analytic
+    defaults in force: a CPU-fit factor never prices a TPU plan."""
+    path = tmp_path / "wire_calibration.json"
+    path.write_text(json.dumps({"version": 2, "backends": {"cpu": {
+        "wire_factors": {"manual": {"int8_ef": 0.123}},
+        "ef_residual_factor": 7.0}}}))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        assert CM.load_wire_calibration(str(path)) is None
+        assert CM.wire_factor("manual", "int8_ef") == \
+            CM.DEFAULT_WIRE_FACTORS["manual"]["int8_ef"]
+        assert CM.ef_residual_factor() == CM.DEFAULT_EF_RESIDUAL_FACTOR
+    finally:
+        CM.reset_wire_calibration()
+
+
 def test_packaged_calibration_overrides_hardcoded_constant():
     """Acceptance: the autotuner's wire costs come from the calibration JSON,
     not the legacy GRAD_WIRE_FACTOR constant — the measured xla-path factor is

@@ -7,7 +7,7 @@ Covers the acceptance criteria:
     engine-level decode;
   * the fused int8 quantize+pack kernel (kernels/fused_quant.py) matches
     the three-op absmax/round/residual sequence bitwise, including the EF
-    residual round-trip, under hypothesis (or the repro.testing stub).
+    residual round-trip, under hypothesis.
 
 Exactness contract: each comparison jits the oracle as one program so both
 sides see identical XLA fusion (the kernel body is always one traced
@@ -220,7 +220,6 @@ def test_reduce_scatter_fused_vs_unfused_paths_agree():
     bitwise contract is covered above where both paths jit alone)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.dist.collectives import (
         manual_int8_ef_reduce_scatter,
         set_fused_quant,
@@ -238,11 +237,11 @@ def test_reduce_scatter_fused_vs_unfused_paths_agree():
         return s[None], ne[None]
 
     def run():
-        return jax.jit(shard_map(
-            body, mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P("data", None, None), P("data", None, None)),
             out_specs=(P("data", None, None), P("data", None, None)),
-            check=False))(g, err0)
+            check_vma=False))(g, err0)
 
     try:
         set_fused_quant(True)
@@ -262,13 +261,11 @@ def test_reduce_scatter_fused_vs_unfused_paths_agree():
 # dispatch plumbing
 # ---------------------------------------------------------------------------
 def test_package_dispatch_and_gating():
-    """The package-level entry points route through pallas_kernels_active();
-    PagedKV auto-gates on it and *always* drops to lax under a host-sharded
-    fetch plan (pallas_call is unpartitionable and cannot read host memory
-    spaces)."""
+    """The package-level entry point runs the kernel; PagedKV uses it by
+    default and *always* drops to lax under a host-sharded fetch plan
+    (pallas_call is unpartitionable and cannot read host memory spaces)."""
     from repro import kernels as K
 
-    assert isinstance(K.pallas_kernels_active(), bool)
     args = _paged_inputs(KEY, 1, 4, 2, 16, 8, 8)
     out = K.decode_paged_attention(*args, n_hot=2)
     ref = _pa_ref(*args)
@@ -276,6 +273,6 @@ def test_package_dispatch_and_gating():
     assert float(jnp.abs(out - ref).max()) == 0.0
 
     spec = choose_paging(16, 4, 2)
-    assert PagedKV(spec).use_kernel == K.pallas_kernels_active()
+    assert PagedKV(spec).use_kernel is True
     assert PagedKV(spec, fetch_sharding=object()).use_kernel is False
     assert PagedKV(spec, use_kernel=False).use_kernel is False

@@ -6,8 +6,7 @@ Covers the acceptance criteria:
     (ring wrap) cases, on the 4-device CI mesh — scalar and per-slot
     positions;
   * the continuous-batching scheduler leaks no slots or pages across
-    admit/evict/finish cycles (property tests, hypothesis or the
-    repro.testing fallback stub);
+    admit/evict/finish cycles (hypothesis property tests);
   * serve_plan emits a paged candidate (n_host > 0) whenever the resident
     cache exceeds the HBM budget while the weights still fit;
   * the decode engine serves a request stream with identical results under
@@ -104,10 +103,9 @@ def test_paged_step_builder_parity_on_ci_mesh():
     from repro.compat import host_memory_kind
 
     kind = host_memory_kind(mesh)
-    if kind is not None:
-        for entry in art_p.state_shardings["cache"].values():
-            assert entry["k_cold"].memory_kind == kind
-            assert entry["v_cold"].memory_kind == kind
+    for entry in art_p.state_shardings["cache"].values():
+        assert entry["k_cold"].memory_kind == kind
+        assert entry["v_cold"].memory_kind == kind
     step_r = jax.jit(art_r.fn)
     step_p = jax.jit(art_p.fn)
     cache_r = jax.tree.map(jax.device_put, KV.init_cache(cfg, B, S),

@@ -7,6 +7,7 @@ Runs under any local device count; CI forces 4 CPU devices via
 XLA_FLAGS=--xla_force_host_platform_device_count=4 so the multi-device
 branches are exercised there."""
 import math
+import types
 
 import jax
 import jax.numpy as jnp
@@ -80,9 +81,7 @@ def test_host_placement_memory_kind_and_roundtrip():
     d = ParamDef((8, 8), (ZERO, TP), dtype="float32")
     s = SH.sharding_for(d, mesh, placement="host")
     kind = host_memory_kind(mesh)
-    if kind is None:
-        pytest.skip("platform exposes no host memory space")
-    assert s.memory_kind == kind  # pinned_host on TPU/GPU, unpinned_host on CPU
+    assert s.memory_kind == kind  # pinned_host on TPU, unpinned_host on CPU
     x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
     hosted = jax.device_put(x, s)
     assert hosted.sharding.memory_kind == kind
@@ -91,6 +90,12 @@ def test_host_placement_memory_kind_and_roundtrip():
     assert g.spec == P(None, "model")
     back = jax.device_put(hosted, g)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
+    # a platform with no host memory space refuses host placement outright
+    # rather than quietly keeping the state in device memory
+    dev_only = types.SimpleNamespace(
+        addressable_memories=lambda: [types.SimpleNamespace(kind="device")])
+    with pytest.raises(RuntimeError, match="no host memory space"):
+        host_memory_kind(types.SimpleNamespace(devices=np.array([dev_only])))
 
 
 # ---------------------------------------------------------------------------

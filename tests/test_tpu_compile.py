@@ -1,0 +1,87 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode runs kernel bodies op by op and accepts blocks the TPU
+compiler refuses (unaligned tiles, scalar stores to VMEM, mixed-dtype
+stores). These tests compile each kernel for a *described* v5e chip — the
+TPU compiler is installed, no chip is needed — and check the kernel is in
+the program (``tpu_custom_call``). Widths are gpt2-1b's (d_model 2048,
+d_ff 8192, 16 heads of 128, seq 1024, batch 8).
+
+The topology is described inside a module fixture only: loading libtpu at
+import time would take its lock in every test worker.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.fused_adam import fused_adam
+from repro.kernels.fused_quant import fused_quantize_ef
+from repro.kernels.paged_attention import paged_attention
+from repro.kernels.rmsnorm import rmsnorm
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import compilation_cache, topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # can never be read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel_compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", [(8192, 2048), (4, 512, 2048), (4, 12565, 2048)],
+                         ids=["activation_rows", "wire_chunk", "wire_chunk_ragged"])
+def test_fused_quantize_ef_compiles(one_chip, shape):
+    _assert_kernel_compiles(fused_quantize_ef,
+                            _sds(shape, jnp.float32, one_chip),
+                            _sds((), jnp.int32, one_chip))
+
+
+def test_fused_adam_compiles(one_chip):
+    p = _sds((2048, 8192), jnp.bfloat16, one_chip)
+    f32 = _sds((2048, 8192), jnp.float32, one_chip)
+    _assert_kernel_compiles(fused_adam, p, p, f32, f32, f32,
+                            _sds((8,), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("scale_dtype", [jnp.bfloat16, jnp.float32])
+def test_rmsnorm_bf16_compiles(one_chip, scale_dtype):
+    _assert_kernel_compiles(rmsnorm, _sds((8, 1024, 2048), jnp.bfloat16, one_chip),
+                            _sds((2048,), scale_dtype, one_chip))
+
+
+def test_flash_attention_compiles(one_chip):
+    qkv = _sds((8, 16, 1024, 128), jnp.bfloat16, one_chip)
+    _assert_kernel_compiles(flash_attention, qkv, qkv, qkv)
+
+
+def test_paged_attention_decode_compiles(one_chip):
+    b, h, hd, page, n_hot, s = 8, 16, 128, 128, 2, 2048
+    hot = _sds((b, page * n_hot, h, hd), jnp.bfloat16, one_chip)
+    cold = _sds((b, s, h, hd), jnp.bfloat16, one_chip)
+    _assert_kernel_compiles(
+        lambda *a: paged_attention(*a, n_hot=n_hot),
+        _sds((b, 1, h, hd), jnp.bfloat16, one_chip), hot, hot, cold, cold,
+        _sds((b, s), jnp.bool_, one_chip), _sds((b, s), jnp.float32, one_chip))
