@@ -22,12 +22,12 @@ import dataclasses
 import json
 import os
 import re
-import time
 from pathlib import Path
 from typing import Callable
 
 import jax
 
+from repro import obs
 from repro.configs import get_config, reduced
 from repro.configs.base import ShapeConfig
 from repro.core import TPU_V5E, build_workload, search
@@ -48,7 +48,13 @@ def enable_compile_cache(root: Path = CHECKOUT_ROOT) -> str:
     """Keep JAX's persistent compilation cache where
     ``JAX_COMPILATION_CACHE_DIR`` says (jax reads it itself), else in
     ``.jax_cache/`` at the checkout root — a fixed path, because the path is
-    part of the cache key. Returns the directory in use."""
+    part of the cache key. Returns the directory in use.
+
+    Cache keys include the HLO's metadata: by default JAX leaves it out, and
+    an executable loaded from the cache then carries the op names of
+    whichever program compiled it first, so a profile would attribute the
+    step's device time to stale ``jax.named_scope`` phases."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -76,7 +82,7 @@ class FitResult:
     search: SearchResult
     art: StepArtifacts
     compiled: jax.stages.Compiled
-    compile_s: float  # seconds to lower + compile the accepted step
+    compile_s: float  # the accepted attempt's plan.lower + plan.compile seconds
     capacity_bytes: float  # planning capacity the accepted search used
     misses: list[tuple[float, float]]  # (modeled peak, compiler overshoot) per refusal
 
@@ -91,29 +97,43 @@ def fit_plan(cfg, shape: ShapeConfig, mesh, hw: HardwareSpec,
     by X bytes, the search runs again with its capacity lowered by X, so
     the plan that runs is still the planner's fastest one — under the
     capacity the compiler can actually deliver.
+
+    Each try is a ``plan.attempt`` span (attributes ``plan``, ``accepted``,
+    ``modeled_peak_bytes``, ``overshoot_bytes``) around ``plan.search``,
+    ``plan.build``, ``plan.lower`` and ``plan.compile`` spans, on the
+    installed telemetry's tracer.
     """
     mspec = MeshSpec(tuple(mesh.devices.shape), tuple(mesh.axis_names))
     w = build_workload(cfg, shape, mspec, hw)
     cap = hw.capacity_bytes()
     misses: list[tuple[float, float]] = []
+    tracer = obs.current_telemetry().tracer
     for _ in range(max_tries):
-        res = search(w, capacity_bytes=cap, sp="auto")
-        art = build(res.plan)
-        t0 = time.perf_counter()
-        try:
-            compiled = art.lower().compile()
-        except jax.errors.JaxRuntimeError as e:
-            over = hbm_overshoot(e)
-            if over is None:
-                raise
-            misses.append((res.memory.peak, over))
-            log(f"[fit] {res.plan.describe()}: modeled peak "
-                f"{res.memory.peak / 1e9:.3f} GB, compiler needs {over / 1e9:.3f} GB "
-                f"more than the device has; searching again at capacity "
-                f"{(cap - over) / 1e9:.3f} GB")
-            cap -= over
-            continue
-        return FitResult(res, art, compiled, time.perf_counter() - t0, cap, misses)
+        with tracer.span("plan.attempt", capacity_bytes=cap, accepted=False) as attempt:
+            with tracer.span("plan.search"):
+                res = search(w, capacity_bytes=cap, sp="auto")
+            attempt.attrs.update(plan=res.plan.describe(), modeled_peak_bytes=res.memory.peak)
+            with tracer.span("plan.build"):
+                art = build(res.plan)
+            with tracer.span("plan.lower") as lowering:
+                lowered = art.lower()
+            try:
+                with tracer.span("plan.compile") as compiling:
+                    compiled = lowered.compile()
+            except jax.errors.JaxRuntimeError as e:
+                over = hbm_overshoot(e)
+                if over is None:
+                    raise
+                attempt.attrs["overshoot_bytes"] = over
+                misses.append((res.memory.peak, over))
+                log(f"[fit] {res.plan.describe()}: modeled peak "
+                    f"{res.memory.peak / 1e9:.3f} GB, compiler needs {over / 1e9:.3f} GB "
+                    f"more than the device has; searching again at capacity "
+                    f"{(cap - over) / 1e9:.3f} GB")
+                cap -= over
+                continue
+            attempt.attrs.update(accepted=True, overshoot_bytes=0.0)
+        return FitResult(res, art, compiled, lowering.dur_s + compiling.dur_s, cap, misses)
     raise RuntimeError(f"no plan fits after {max_tries} compiles: {misses}")
 
 

@@ -238,6 +238,8 @@ DOCUMENTED_METRICS = (
     # train/sync.py + step_builder
     "sync.wire_bytes_per_step",
     "sync.wire_payload",
+    # train/step_builder.py
+    "offload.bytes_per_step",
     # serve/engine.py + serve/scheduler.py
     "serve.ticks",
     "serve.generated_tokens",

@@ -1,35 +1,43 @@
-"""Structured span tracer: nestable wall-clock spans, Chrome-trace export.
+"""Structured span tracer: nestable wall-clock spans on the profiler's clock.
 
-``tracer.span("gather_prefetch")`` is a context manager; spans nest through
-a per-thread stack so concurrent engine/loop threads interleave without
-locking the hot path (only the shared event list append is locked). Two
-export forms:
+``tracer.span("plan.compile")`` is a context manager; spans nest through a
+per-thread stack so concurrent engine/loop threads interleave without
+locking the hot path (only the shared event list append is locked). Each
+retained event carries an ``id`` and the ``parent`` id of the span that
+enclosed it on its thread (None at the top).
 
-  * ``write_jsonl(path)`` — one event per line, machine-grep-friendly;
-  * ``write_chrome_trace(path)`` / ``to_chrome_trace()`` — the Chrome
-    trace-event JSON (``{"traceEvents": [...]}``) Perfetto and
-    ``chrome://tracing`` load directly: complete ("ph": "X") events with
-    microsecond ``ts``/``dur``, instant ("ph": "i") marks, and process/
-    thread-name metadata ("ph": "M").
+Every span also opens a ``jax.profiler.TraceAnnotation`` of its own name
+(a ``StepTraceAnnotation`` when it has a ``step`` attribute), whether or not
+the tracer retains events, so under a ``jax.profiler`` session the span
+lands in the device trace beside the device's operations. With no
+profiler session an annotation costs about a microsecond.
+
+``write_chrome_trace(path)`` / ``to_chrome_trace()`` export the retained
+spans as Chrome trace-event JSON (``{"traceEvents": [...]}``) that Perfetto
+and ``chrome://tracing`` load directly: complete ("ph": "X") events with
+microsecond ``ts``/``dur`` and process/thread-name metadata ("ph": "M").
 
 Disabled tracers still *measure* (two ``perf_counter`` reads — the span
 object's ``dur_s`` is always valid, which is what lets benchmark drivers use
-one clock for their own reporting) but retain nothing, so the retained-event
-path costs zero when telemetry is off.
+one clock for their own reporting) but retain nothing.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 class Span:
     """One timed region. ``dur_s`` is valid after the ``with`` block exits
     whether or not the tracer retains events."""
 
-    __slots__ = ("name", "attrs", "t0_s", "dur_s", "depth", "_tracer")
+    __slots__ = ("name", "attrs", "t0_s", "dur_s", "id", "parent", "_tracer",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -37,16 +45,23 @@ class Span:
         self.attrs = attrs
         self.t0_s = 0.0
         self.dur_s = 0.0
-        self.depth = 0
+        self.id = next(tracer._ids)
+        self.parent: int | None = None
 
     def __enter__(self) -> "Span":
-        self.depth = len(self._tracer._stack_of(threading.get_ident()))
-        self._tracer._stack_of(threading.get_ident()).append(self)
+        stack = self._tracer._stack_of(threading.get_ident())
+        self.parent = stack[-1].id if stack else None
+        stack.append(self)
+        step = self.attrs.get("step")
+        self._annotation = (TraceAnnotation(self.name) if step is None
+                            else StepTraceAnnotation(self.name, step_num=int(step)))
+        self._annotation.__enter__()
         self.t0_s = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.dur_s = time.perf_counter() - self.t0_s
+        self._annotation.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack_of(threading.get_ident())
         if stack and stack[-1] is self:
             stack.pop()
@@ -54,8 +69,9 @@ class Span:
 
 
 class Tracer:
-    """Span recorder. ``enabled=False`` keeps the timing contract but drops
-    every event (the no-op used when telemetry is off)."""
+    """Span recorder. ``enabled=False`` keeps the timing contract (and the
+    profiler annotations) but drops every event (the no-op used when
+    telemetry is off)."""
 
     def __init__(self, enabled: bool = True, max_events: int = 1 << 18):
         self.enabled = enabled
@@ -63,6 +79,7 @@ class Tracer:
         self.events: list[dict] = []
         self._lock = threading.Lock()
         self._stacks: dict[int, list] = {}
+        self._ids = itertools.count()
         self._epoch = time.perf_counter()
 
     def _stack_of(self, tid: int) -> list:
@@ -74,23 +91,12 @@ class Tracer:
     def span(self, name: str, **attrs) -> Span:
         return Span(self, name, attrs)
 
-    def instant(self, name: str, **attrs) -> None:
-        """A zero-duration mark (Chrome "i" event)."""
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": "i",
-              "ts_s": time.perf_counter() - self._epoch, "dur_s": 0.0,
-              "tid": threading.get_ident(), "depth": 0, "args": attrs}
-        with self._lock:
-            if len(self.events) < self.max_events:
-                self.events.append(ev)
-
     def _record(self, span: Span) -> None:
         if not self.enabled:
             return
         ev = {"name": span.name, "ph": "X",
               "ts_s": span.t0_s - self._epoch, "dur_s": span.dur_s,
-              "tid": threading.get_ident(), "depth": span.depth,
+              "tid": threading.get_ident(), "id": span.id, "parent": span.parent,
               "args": span.attrs}
         with self._lock:
             if len(self.events) < self.max_events:
@@ -112,13 +118,10 @@ class Tracer:
         for e in events:
             rec = {"name": e["name"], "ph": e["ph"], "pid": 0,
                    "tid": tid_ix[e["tid"]],
-                   "ts": round(e["ts_s"] * 1e6, 3)}
-            if e["ph"] == "X":
-                rec["dur"] = round(e["dur_s"] * 1e6, 3)
-            if e["ph"] == "i":
-                rec["s"] = "t"  # instant scope: thread
+                   "ts": round(e["ts_s"] * 1e6, 3),
+                   "dur": round(e["dur_s"] * 1e6, 3)}
             if e["args"]:
-                rec["args"] = {k: v for k, v in e["args"].items()}
+                rec["args"] = dict(e["args"])
             out.append(rec)
         return {"traceEvents": out, "displayTimeUnit": "ms"}
 
@@ -127,15 +130,6 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(process_name), f)
             f.write("\n")
-        return path
-
-    def write_jsonl(self, path: str) -> str:
-        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-        with self._lock:
-            events = list(self.events)
-        with open(path, "w") as f:
-            for e in events:
-                f.write(json.dumps(e, default=str) + "\n")
         return path
 
 

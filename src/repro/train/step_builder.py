@@ -25,6 +25,7 @@ multi-pod dry-run can ``.lower().compile()`` without allocating anything.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable
 
 import jax
@@ -120,6 +121,32 @@ class StepArtifacts:
 
     def lower(self, donate: bool = True):
         return self.jit(donate).lower(self.state_specs, self.batch_specs)
+
+
+def record_offload_inventory(state_specs, microbatch: int) -> None:
+    """Record the bytes one step moves over each device's host link, per
+    direction, as the gauge ``offload.bytes_per_step{dir=fetch|writeback}``.
+
+    The host-offloaded Adam update brings every host-placed fp32 master/m/v
+    leaf to the device and sends it back once a step; host-placed bf16
+    parameters are fetched by every microbatch's forward and written back
+    once by the update. Counted from the state specs' memory kinds and
+    per-device shard shapes; a parameter fetched again for recomputation
+    is not counted. Like ``sync.record_sync_inventory``, a no-op without an
+    installed telemetry handle.
+    """
+    from repro import obs
+
+    reg = obs.current_telemetry().registry
+
+    def host_bytes(tree) -> int:
+        return sum(math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
+                   for s in jax.tree.leaves(tree)
+                   if s.sharding.memory_kind not in (None, "device"))
+
+    opt, params = host_bytes(state_specs["opt"]), host_bytes(state_specs["params"])
+    reg.gauge("offload.bytes_per_step", dir="fetch").set(opt + params * microbatch)
+    reg.gauge("offload.bytes_per_step", dir="writeback").set(opt + params)
 
 
 def _opt_placement(placement: str, plan: MemoryPlan) -> str:
@@ -357,6 +384,7 @@ def build_train_step(
         ``full=True``: params arrive pre-gathered to full leaves, so the
         device_put-based fetch/gather machinery is bypassed entirely."""
 
+        @jax.named_scope("model")  # forward: jvp(model); backward: transpose(jvp(model))
         def loss_fn(params, batch):
             M.set_activation_sharder(act_sharder)
             fparams = params if full else fetch(params)
@@ -457,6 +485,7 @@ def build_train_step(
                 ))
             return out
 
+        @jax.named_scope("model")
         def lazy_loss(params, ef, batch):
             M.set_activation_sharder(lambda x, kind="bsd": x)
             fparams = dict(params)
@@ -505,14 +534,17 @@ def build_train_step(
     tp_degree = SH.mesh_sizes(mesh).get("model", 1)
     strategy = SYNC.make_strategy(plan, mesh, tp_degree)
     # telemetry (host-side, no-op without an installed handle): the step's
-    # static collective wire-byte inventory — collectives run inside jit, so
-    # this is recorded from the leaf specs, not counted at runtime
+    # static collective wire-byte and host-link byte inventories — both move
+    # inside jit, so they are recorded from the leaf specs, not counted at
+    # runtime
     SYNC.record_sync_inventory(strategy, state_specs["params"], plan.microbatch)
+    record_offload_inventory(state_specs, plan.microbatch)
     compress = plan.grad_compress
     ef_layout = strategy.ef_state(o_defs_one, g_shard)
     if ef_layout is not None:
         state_specs["ef"], state_shardings["ef"] = ef_layout
 
+    @jax.named_scope("optimizer")
     def apply_update(state, grads, total, ce, new_ef, metrics, *,
                      host_plan, repin, grad_norm=None):
         """Optimizer update + new-state/metrics assembly, shared tail of both

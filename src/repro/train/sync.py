@@ -64,9 +64,13 @@ _is_sds = lambda x: isinstance(x, jax.ShapeDtypeStruct)  # noqa: E731
 # ---------------------------------------------------------------------------
 # Shared accumulate skeleton (both sync paths, all manual kinds)
 # ---------------------------------------------------------------------------
+@jax.named_scope("accumulate")
 def accumulate_grads(micro_grad, batch, microbatch, ef, acc_like, pin=None,
                      overlap=False):
     """Microbatch gradient accumulation, shared by every sync strategy.
+    Runs under ``jax.named_scope("accumulate")``: the microbatch loop's own
+    work (the gradient buffers, each microbatch's fold into them, the final
+    mean) carries that scope, around the loss's ``model`` scope.
 
     ``micro_grad(mb_batch, ef) -> (grads, total, ce, ef)`` computes one
     microbatch's gradients — already synced for the manual strategies (the
