@@ -219,7 +219,7 @@ def search(
     # int8+EF gradient-sync wire compression; "auto" by default — the wire
     # factors are calibrated (cost_model.wire_factor), so weighing the knob
     # costs nothing and the search is honest about when compression pays.
-    compress: str = "auto",  # "off" | "on" | "auto"
+    compress: str = "auto",  # "off" | "on" | "auto" ("none" only on one chip)
     sync: str = "auto",  # "xla" | "manual" | "auto": who owns the grad reduce
     # comm/compute overlap on the manual path: candidates are priced with the
     # prefetch/deferred-accumulation pipeline on (plan.overlap). Pass False to
@@ -236,6 +236,10 @@ def search(
     sp_vals = {"off": (False,), "on": (True,), "auto": (False, True)}[sp]
     dp_vals = {"off": (False,), "on": (True,), "auto": (False, True)}[dp]
     gc_only = {"off": ("none",), "on": ("int8_ef",), "auto": ("none", "int8_ef")}[compress]
+    if compress == "auto" and w.mesh.n_chips == 1:
+        # one device has no wire to save: int8+EF would only round every
+        # gradient to 8 bits, a lower precision than the model states
+        gc_only = ("none",)
     sync_only = {"xla": ("xla",), "manual": ("manual",), "auto": ("xla", "manual")}[sync]
     # (grad_compress, sync_mode) combos: manual sync without compression has
     # no upside over XLA's native reduce, so it is never proposed

@@ -240,6 +240,7 @@ DOCUMENTED_METRICS = (
     "sync.wire_payload",
     # train/step_builder.py
     "offload.bytes_per_step",
+    "offload.streamed_bytes_per_step",
     # serve/engine.py + serve/scheduler.py
     "serve.ticks",
     "serve.generated_tokens",

@@ -9,6 +9,7 @@ what the CPU tests run.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -43,33 +44,34 @@ def global_norm(tree) -> jax.Array:
     return jnp.sqrt(sum(leaves))
 
 
-def clip_by_global_norm(grads, max_norm: float, norm: jax.Array | None = None):
-    """``norm`` overrides the locally-computed global norm — the manual ZeRO
-    sync path holds shard-sized gradient leaves, so the true global norm
-    needs a cross-device reduction the caller owns (train/sync.py)."""
+def global_norm_clip(grads, max_norm: float, norm: jax.Array | None = None):
+    """The clip to ``max_norm`` of the gradients' global norm, as a function
+    of one gradient leaf (or slice of one), with that norm: the streamed
+    update clips one layer at a time, so no clipped copy of a whole leaf is
+    made. ``norm`` overrides the locally-computed global norm — the manual
+    ZeRO sync path holds shard-sized gradient leaves, so the true global
+    norm needs a cross-device reduction the caller owns (train/sync.py)."""
     norm = global_norm(grads) if norm is None else norm
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-12))
-    return jax.tree.map(lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype), grads), norm
+    return (lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype)), norm
 
 
-def _update_leaf(p, g, master, m, v, *, cfg: AdamConfig, lr, bc1, bc2, fused: bool,
-                 host: tuple | None = None):
-    """One Adam leaf update. ``host`` = (param_shard, opt_host_shard,
-    opt_dev_shard) for host-offloaded chunks: optimizer states round-trip
-    device<->host (the TPU adaptation of the paper's CPU Adam — XLA schedules
-    the DMA off the critical path; see DESIGN.md)."""
-    if host is not None:
-        p_shard, h_shard, d_shard = host
-        master = jax.device_put(master, d_shard)
-        m = jax.device_put(m, d_shard)
-        v = jax.device_put(v, d_shard)
-    if fused and host is None:
-        from repro.kernels import fused_adam_update
+@dataclasses.dataclass(frozen=True)
+class HostLeaf:
+    """Placement of one host-offloaded leaf through its update: ``param`` is
+    the new parameter's sharding, ``host`` the fp32 states' in host memory,
+    ``device`` the states' while they are updated. ``stacked``: the leaf's
+    leading axis is its layer axis (a run's stacked block leaf)."""
 
-        return fused_adam_update(
-            p, g, master, m, v, lr=lr, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps,
-            weight_decay=cfg.weight_decay, bc1=bc1, bc2=bc2,
-        )
+    param: Any
+    host: Any
+    device: Any
+    stacked: bool = False
+
+
+def _adam_math(g, master, m, v, dtype, *, cfg: AdamConfig, lr, bc1, bc2):
+    """The elementwise Adam update of one leaf or one layer of it: the same
+    expression per element either way, so both give the same bits."""
     gf = g.astype(jnp.float32)
     m_new = cfg.b1 * m + (1 - cfg.b1) * gf
     v_new = cfg.b2 * v + (1 - cfg.b2) * gf * gf
@@ -79,13 +81,100 @@ def _update_leaf(p, g, master, m, v, *, cfg: AdamConfig, lr, bc1, bc2, fused: bo
     if cfg.weight_decay:
         upd = upd + cfg.weight_decay * master
     master_new = master - lr * upd
-    p_new = master_new.astype(p.dtype)
+    return master_new.astype(dtype), master_new, m_new, v_new
+
+
+def _update_leaf(p, g, master, m, v, *, cfg: AdamConfig, lr, bc1, bc2, fused: bool,
+                 host: HostLeaf | None = None):
+    """One Adam leaf update, whole. A host-offloaded leaf (``host``) brings
+    its fp32 master/m/v to the device, updates them there and writes them
+    back (the TPU adaptation of the paper's CPU Adam): the writeback can
+    start only once the whole leaf is updated, and the update only once the
+    whole leaf has arrived. Stacked leaves that qualify stream instead
+    (``_stream_update``)."""
     if host is not None:
-        p_new = jax.device_put(p_new, p_shard)
-        master_new = jax.device_put(master_new, h_shard)
-        m_new = jax.device_put(m_new, h_shard)
-        v_new = jax.device_put(v_new, h_shard)
-    return p_new, master_new, m_new, v_new
+        master, m, v = (jax.device_put(x, host.device) for x in (master, m, v))
+    elif fused:
+        from repro.kernels import fused_adam_update
+
+        return fused_adam_update(
+            p, g, master, m, v, lr=lr, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps,
+            weight_decay=cfg.weight_decay, bc1=bc1, bc2=bc2,
+        )
+    out = _adam_math(g, master, m, v, p.dtype, cfg=cfg, lr=lr, bc1=bc1, bc2=bc2)
+    if host is None:
+        return out
+    p_new, *states = out
+    return (jax.device_put(p_new, host.param),
+            *(jax.device_put(x, host.host) for x in states))
+
+
+def _leading_unsharded(sharding) -> bool:
+    spec = getattr(sharding, "spec", None)
+    return spec is not None and (len(spec) == 0 or spec[0] is None)
+
+
+def streams(p, host: HostLeaf | None) -> bool:
+    """Whether a leaf's update streams layer by layer: a host-offloaded
+    stacked leaf whose layer axis leads, is not sharded, and is neither of
+    the two minor (tiled) dims. A per-layer slice of a 2-D stacked leaf
+    ``[L, d]`` would cut across the TPU's (8, 128) tile on its second-minor
+    dim, a DMA the chip refuses."""
+    return (host is not None and host.stacked and p.ndim >= 3 and p.shape[0] >= 2
+            and all(map(_leading_unsharded, (host.param, host.host, host.device))))
+
+
+def _per_layer(sharding):
+    """A stacked leaf's sharding for one layer of it (the layer axis dropped)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    return NamedSharding(sharding.mesh, PartitionSpec(*sharding.spec[1:]),
+                         memory_kind=sharding.memory_kind)
+
+
+def _stream_update(ps, gs, masters, ms, vs, hosts, clip, math):
+    """Adam over stacked host leaves of one layer count, one layer at a time.
+
+    One loop over the layer axis updates layer i of every leaf while layer
+    i+1's states are fetched and layer i-1's written back: the fetched
+    states and the updated ones ride the carry (a double buffer), and each
+    writeback lands in the carried host buffers (donated by the step, so
+    in place). The device holds a few layers' states, not whole leaves,
+    and both directions of the host link carry data at once.
+    """
+    n = ps[0].shape[0]
+    dev = [_per_layer(h.device) for h in hosts]
+    hst = [_per_layer(h.host) for h in hosts]
+    par = [_per_layer(h.param) for h in hosts]
+    row = lambda x, i: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False)  # noqa: E731
+
+    def fetch(i, bufs):
+        return [tuple(jax.device_put(row(x, i), d) for x in b[1:]) for b, d in zip(bufs, dev)]
+
+    def update(i, fetched):
+        return [math(clip(row(g, i)), *f, p.dtype) for g, f, p in zip(gs, fetched, ps)]
+
+    def write(i, new, bufs):
+        out = []
+        for b, (p_new, *states), pd, hd in zip(bufs, new, par, hst):
+            out.append((jax.lax.dynamic_update_index_in_dim(b[0], jax.device_put(p_new, pd), i, 0),
+                        *(jax.lax.dynamic_update_index_in_dim(x, jax.device_put(y, hd), i, 0)
+                          for x, y in zip(b[1:], states))))
+        return out
+
+    def body(i, carry):
+        fetched, new, bufs = carry
+        ahead = fetch(i + 1, bufs)  # reads layer i+1 before the writeback below
+        return ahead, update(i, fetched), write(i - 1, new, bufs)
+
+    # placed where the step keeps them (a no-op on TPU; the CPU backend
+    # hands host-placed state to a step in device memory)
+    bufs = [(jax.device_put(p, h.param), *(jax.device_put(x, h.host) for x in st))
+            for p, h, *st in zip(ps, hosts, masters, ms, vs)]
+    first, second = fetch(0, bufs), fetch(1, bufs)
+    fetched, new, bufs = jax.lax.fori_loop(1, n - 1, body, (second, update(0, first), bufs))
+    bufs = write(n - 2, new, bufs)
+    return write(n - 1, update(n - 1, fetched), bufs)
 
 
 def adam_update(params, grads, opt_state, cfg: AdamConfig, lr: float | jax.Array,
@@ -93,27 +182,37 @@ def adam_update(params, grads, opt_state, cfg: AdamConfig, lr: float | jax.Array
     """Returns (new_params, new_opt_state, grad_norm).
 
     ``host_plan``: optional flat list aligned with the flattened params; each
-    entry is None or (param_sharding, opt_host_sharding, opt_device_sharding)
-    marking a host-offloaded leaf. ``grad_norm``: externally-computed global
+    entry is None or the ``HostLeaf`` of a host-offloaded leaf. Leaves that
+    ``streams`` admits are updated layer by layer, one loop per layer
+    count; the other host leaves are updated whole, first, so that their
+    copies overlap the loops. ``grad_norm``: externally-computed global
     norm for clipping (manual ZeRO sync: leaves are device-local shards)."""
-    grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip, norm=grad_norm)
+    clip, gnorm = global_norm_clip(grads, cfg.grad_clip, grad_norm)
     count = opt_state["count"] + 1
     bc1 = 1 - cfg.b1 ** count.astype(jnp.float32)
     bc2 = 1 - cfg.b2 ** count.astype(jnp.float32)
 
     flat_p, treedef = jax.tree.flatten(params)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_master = treedef.flatten_up_to(opt_state["master"])
-    flat_m = treedef.flatten_up_to(opt_state["m"])
-    flat_v = treedef.flatten_up_to(opt_state["v"])
+    flat = [flat_p] + [treedef.flatten_up_to(t) for t in
+                       (grads, opt_state["master"], opt_state["m"], opt_state["v"])]
     if host_plan is None:
         host_plan = [None] * len(flat_p)
 
-    outs = [
-        _update_leaf(p, g, ma, m, v, cfg=cfg, lr=lr, bc1=bc1, bc2=bc2,
-                     fused=cfg.use_fused_kernel, host=h)
-        for p, g, ma, m, v, h in zip(flat_p, flat_g, flat_master, flat_m, flat_v, host_plan)
-    ]
+    outs: list = [None] * len(flat_p)
+    groups: dict[int, list[int]] = {}
+    for k, (p, h) in enumerate(zip(flat_p, host_plan)):
+        if streams(p, h):
+            groups.setdefault(p.shape[0], []).append(k)
+            continue
+        p, g, *states = (x[k] for x in flat)
+        outs[k] = _update_leaf(p, clip(g), *states, cfg=cfg, lr=lr, bc1=bc1, bc2=bc2,
+                               fused=cfg.use_fused_kernel, host=h)
+    math = functools.partial(_adam_math, cfg=cfg, lr=lr, bc1=bc1, bc2=bc2)
+    for ks in groups.values():
+        new = _stream_update(*([x[k] for k in ks] for x in flat),
+                             [host_plan[k] for k in ks], clip, math)
+        for k, o in zip(ks, new):
+            outs[k] = o
     new_p = treedef.unflatten([o[0] for o in outs])
     new_state = {
         "master": treedef.unflatten([o[1] for o in outs]),

@@ -39,7 +39,7 @@ from repro.dist import collectives as COLL
 from repro.dist import sharding as SH
 from repro.models import kvcache as KV
 from repro.models import model as M
-from repro.models.layers import ParamDef
+from repro.models.layers import LAYER, ParamDef
 from repro.optim import adam as OPT
 from repro.train import sync as SYNC
 from repro.train.losses import chunked_cross_entropy
@@ -123,30 +123,49 @@ class StepArtifacts:
         return self.jit(donate).lower(self.state_specs, self.batch_specs)
 
 
-def record_offload_inventory(state_specs, microbatch: int) -> None:
+def record_offload_inventory(state_specs, microbatch: int, host_plan: list) -> None:
     """Record the bytes one step moves over each device's host link, per
-    direction, as the gauge ``offload.bytes_per_step{dir=fetch|writeback}``.
+    direction, as the gauge ``offload.bytes_per_step{dir=fetch|writeback}``,
+    and the part of them the layer-streamed update moves as
+    ``offload.streamed_bytes_per_step{dir=...}``.
 
     The host-offloaded Adam update brings every host-placed fp32 master/m/v
     leaf to the device and sends it back once a step; host-placed bf16
     parameters are fetched by every microbatch's forward and written back
     once by the update. Counted from the state specs' memory kinds and
     per-device shard shapes; a parameter fetched again for recomputation
-    is not counted. Like ``sync.record_sync_inventory``, a no-op without an
+    is not counted. ``host_plan`` (the update's ``HostLeaf`` per parameter
+    leaf) names the leaves that stream (``optim.adam.streams``): their
+    states both ways, and their new parameters when those live on the
+    host. Like ``sync.record_sync_inventory``, a no-op without an
     installed telemetry handle.
     """
     from repro import obs
 
     reg = obs.current_telemetry().registry
 
+    def nbytes(s) -> int:
+        return math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
+
+    def on_host(s) -> bool:
+        return s.sharding.memory_kind not in (None, "device")
+
     def host_bytes(tree) -> int:
-        return sum(math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
-                   for s in jax.tree.leaves(tree)
-                   if s.sharding.memory_kind not in (None, "device"))
+        return sum(nbytes(s) for s in jax.tree.leaves(tree) if on_host(s))
 
     opt, params = host_bytes(state_specs["opt"]), host_bytes(state_specs["params"])
     reg.gauge("offload.bytes_per_step", dir="fetch").set(opt + params * microbatch)
     reg.gauge("offload.bytes_per_step", dir="writeback").set(opt + params)
+
+    p_flat = jax.tree.leaves(state_specs["params"])
+    states = zip(*(jax.tree.leaves(state_specs["opt"][k]) for k in ("master", "m", "v")))
+    fetch = back = 0
+    for p, h, st in zip(p_flat, host_plan, states):
+        if OPT.streams(p, h):
+            fetch += sum(map(nbytes, st))
+            back += sum(map(nbytes, st)) + (nbytes(p) if on_host(p) else 0)
+    reg.gauge("offload.streamed_bytes_per_step", dir="fetch").set(fetch)
+    reg.gauge("offload.streamed_bytes_per_step", dir="writeback").set(back)
 
 
 def _opt_placement(placement: str, plan: MemoryPlan) -> str:
@@ -245,15 +264,16 @@ def build_train_step(
     opt_defs = {"master": o_defs_one, "m": o_defs_one, "v": o_defs_one}
     opt_shard = {"master": o_shard_one, "m": o_shard_one, "v": o_shard_one}
 
-    # host-offloaded leaves: (param shard, opt host shard, opt device shard)
+    # host-offloaded leaves: where each is updated (optim/adam.HostLeaf)
     def host_entry(d: ParamDef, pl: str):
         if pl != "host" or not plan.host_optimizer:
             return None
         df = fp32_def(d)
-        return (
+        return OPT.HostLeaf(
             SH.sharding_for(d, mesh, placement=param_place("host"), dp_only=dp),
             SH.sharding_for(df, mesh, placement="host", dp_only=dp),
             SH.sharding_for(df, mesh, placement="hbm", dp_only=dp),
+            stacked=bool(d.axes) and d.axes[0] == LAYER,
         )
 
     host_plan_flat = [
@@ -538,7 +558,7 @@ def build_train_step(
     # inside jit, so they are recorded from the leaf specs, not counted at
     # runtime
     SYNC.record_sync_inventory(strategy, state_specs["params"], plan.microbatch)
-    record_offload_inventory(state_specs, plan.microbatch)
+    record_offload_inventory(state_specs, plan.microbatch, host_plan_flat)
     compress = plan.grad_compress
     ef_layout = strategy.ef_state(o_defs_one, g_shard)
     if ef_layout is not None:

@@ -391,6 +391,27 @@ def test_offload_inventory_zero_without_host_chunks():
     snap = tel.registry.snapshot()
     assert snap["offload.bytes_per_step{dir=fetch}"]["value"] == 0
     assert snap["offload.bytes_per_step{dir=writeback}"]["value"] == 0
+    assert snap["offload.streamed_bytes_per_step{dir=fetch}"]["value"] == 0
+    assert snap["offload.streamed_bytes_per_step{dir=writeback}"]["value"] == 0
+
+
+def test_streamed_offload_share_for_gpt2_1b():
+    """gpt2-1b under its one-chip plan (every chunk's fp32 state on the host,
+    bf16 params in HBM): the six stacked projection leaves stream, 0.898 of
+    the host-link bytes each way; the embedding and the norms do not."""
+    from repro.configs.paper_models import GPT2_1B
+
+    shape = ShapeConfig("paper", 1024, 8, "train")
+    plan = MemoryPlan(20, 18, n_host=20, microbatch=8, host_params=False)
+    tel = obs.Telemetry(trace=False)
+    with obs.use_telemetry(tel):
+        SB.build_train_step(GPT2_1B, plan, make_local_mesh(), shape)
+    snap = tel.registry.snapshot()
+    for d in ("fetch", "writeback"):
+        total = snap[f"offload.bytes_per_step{{dir={d}}}"]["value"]
+        streamed = snap[f"offload.streamed_bytes_per_step{{dir={d}}}"]["value"]
+        assert (total, streamed) == (12_108_570_624, 10_871_635_968)
+        assert round(streamed / total, 3) == 0.898
 
 
 # ---------------------------------------------------------------------------

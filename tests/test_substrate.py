@@ -59,12 +59,12 @@ def test_cosine_schedule_shape():
 
 
 def test_grad_clip():
-    from repro.optim.adam import clip_by_global_norm
+    from repro.optim.adam import global_norm_clip
 
     g = {"a": jnp.full((10,), 100.0)}
-    clipped, norm = clip_by_global_norm(g, 1.0)
+    clip, norm = global_norm_clip(g, 1.0)
     assert float(norm) > 100
-    total = jnp.sqrt(jnp.sum(jnp.square(clipped["a"])))
+    total = jnp.sqrt(jnp.sum(jnp.square(clip(g["a"]))))
     assert abs(float(total) - 1.0) < 1e-3
 
 
@@ -198,3 +198,104 @@ def test_train_loop_resumes_from_checkpoint(tiny_artifacts, tmp_path):
     assert res2.resumed_from == 10
     assert res2.steps_run == 5
     assert pipe2.step >= 15  # data state restored, not restarted
+
+
+# ---------------------------------------------------------------------------
+# host-offloaded Adam: layer-streamed vs whole-leaf updates
+# ---------------------------------------------------------------------------
+def _host_update_setup(n_layers: int):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.compat import host_memory_kind
+
+    mesh = local_mesh()
+    dev = NamedSharding(mesh, P())
+    host = NamedSharding(mesh, P(), memory_kind=host_memory_kind(mesh))
+    # a rank-3 stacked leaf streams; a rank-2 stacked one and an unstacked
+    # one take the whole-leaf path
+    shapes = {"stack3": (n_layers, 16, 24), "stack2": (n_layers, 16), "flat": (16, 24)}
+    keys = jax.random.split(KEY, 3 * len(shapes))
+    params = {n: jax.random.normal(keys[i], s, jnp.bfloat16)
+              for i, (n, s) in enumerate(shapes.items())}
+    grads = [{n: jax.random.normal(keys[len(shapes) * (j + 1) + i], s, jnp.bfloat16)
+              for i, (n, s) in enumerate(shapes.items())} for j in range(2)]
+    names = jax.tree.leaves({n: n for n in shapes})  # flatten order
+    return params, grads, names, dev, host
+
+
+@pytest.mark.parametrize("n_layers", [2, 5], ids=["two_layers", "odd_layers"])
+def test_streamed_host_update_bitwise_equals_whole_leaf(n_layers):
+    from repro.optim.adam import HostLeaf, streams
+
+    params, grads, names, dev, host = _host_update_setup(n_layers)
+    cfg = AdamConfig(lr=1e-2, grad_clip=0.5, weight_decay=0.1)
+
+    def plan(stream: bool):
+        return [HostLeaf(dev, host, dev, stacked=stream and n != "flat") for n in names]
+
+    assert [streams(params[n], h) for n, h in zip(names, plan(True))] == [
+        n == "stack3" for n in names]
+    assert not any(streams(params[n], h) for n, h in zip(names, plan(False)))
+
+    def two_steps(host_plan):
+        step = jax.jit(lambda p, g, o: adam_update(p, g, o, cfg, cfg.lr, host_plan=host_plan))
+        p, o = params, init_opt_state(params)
+        for g in grads:
+            p, o, _ = step(p, g, o)
+        return p, o
+
+    streamed, whole, device = two_steps(plan(True)), two_steps(plan(False)), two_steps(None)
+    for ref in (whole, device):
+        for a, b in zip(jax.tree.leaves(streamed), jax.tree.leaves(ref)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _three_layer_setup():
+    from repro.core import build_workload
+    from repro.core.hardware import LOCAL_CPU_HW, MeshSpec
+
+    cfg = reduced(ARCHS["llama3-405b"], num_layers=3, d_model=64, d_ff=128,
+                  vocab_size=256, num_heads=2, num_kv_heads=2, head_dim=32)
+    shape = ShapeConfig("host_stream", 32, 2, "train")
+    w = build_workload(cfg, shape, MeshSpec((1, 1), ("data", "model")), LOCAL_CPU_HW)
+    return cfg, shape, w
+
+
+@pytest.mark.parametrize("host_params", [True, False], ids=["params_on_host", "params_in_hbm"])
+def test_all_host_step_equals_all_device_step(host_params):
+    """One train step with every chunk's optimizer state on the host (the
+    stacked block leaves streamed layer by layer) gives the same bits as
+    the same step with nothing on the host."""
+    from repro import obs
+
+    cfg, shape, w = _three_layer_setup()
+    mesh = local_mesh()
+    tel = obs.Telemetry(trace=False)
+    with obs.use_telemetry(tel):
+        host_art = build_train_step(cfg, MemoryPlan(w.n_chunks, w.n_blocks, n_host=w.n_chunks,
+                                                    host_params=host_params), mesh, shape)
+    assert tel.registry.snapshot()["offload.streamed_bytes_per_step{dir=fetch}"]["value"] > 0
+    dev_art = build_train_step(cfg, MemoryPlan(w.n_chunks, w.n_blocks, n_host=0), mesh, shape)
+    state = dev_art.init(KEY)
+    batch = SyntheticTokenPipeline(cfg, shape, seed=5).next_sync()
+    dev_out, dev_metrics = dev_art.jit(donate=False)(state, batch)
+    host_state = jax.tree.map(jax.device_put, state, host_art.state_shardings)
+    host_out, host_metrics = host_art.jit(donate=False)(host_state, batch)
+    assert float(host_metrics["loss"]) == float(dev_metrics["loss"])
+    for a, b in zip(jax.tree.leaves(host_out), jax.tree.leaves(dev_out)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_search_offers_no_gradient_compression_on_one_device():
+    """One device has no wire: ``compress="auto"`` never picks int8+EF
+    there (it would only round the gradients), while ``"on"`` still can."""
+    from repro.configs.paper_models import GPT2_1B
+    from repro.core import build_workload, search
+    from repro.core.hardware import TPU_V5E, MeshSpec
+
+    w = build_workload(GPT2_1B, ShapeConfig("paper", 1024, 8, "train"),
+                       MeshSpec((1, 1), ("data", "model")), TPU_V5E)
+    for cap in (None, 10.7e9):
+        assert search(w, capacity_bytes=cap, sp="auto").plan.grad_compress == "none"
+    assert search(w, compress="on").plan.grad_compress == "int8_ef"
