@@ -22,24 +22,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from reference.model import rotary_fraction
 from weights import Leaf, global_leaves, layer_leaves, make_leaf, make_stack
 
 MLP_KINDS = {"gelu_tanh": "gelu", "swiglu": "swiglu"}
+# what the program's blocks do where its ModelConfig has no field to say so
+BUILT_IN = {"rotary_fraction": 1.0, "norm_eps": 1e-6}
 
 
 def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file. The rotary
+    fraction and the norm's eps go to ``ModelConfig`` fields of those names
+    where it has them; where it has not, a file that asks for another value
+    than the program's built-in one is refused, naming the key, so that the
+    program never runs other equations than the file states."""
     from repro.configs.base import ModelConfig
 
-    if cfg["positions"] != "rope_full_head" or cfg["norm"] != "layernorm":
-        raise ValueError(f"{cfg['name']}: the program runs full-head rope and layernorm only")
+    if cfg["norm"] != "layernorm":
+        raise ValueError(f"{cfg['name']}: the program runs layernorm only")
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    stated = {"rotary_fraction": rotary_fraction(cfg), "norm_eps": cfg["norm_eps"]}
+    extra = {}
+    for key, value in stated.items():
+        if key in fields:
+            extra[key] = value
+        elif value != BUILT_IN[key]:
+            raise ValueError(f"{cfg['name']}: {key} {value} is not the program's built-in "
+                             f"{BUILT_IN[key]}, and its ModelConfig has no field {key!r}")
     return ModelConfig(
         name=cfg["name"], family="dense",
         num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
         num_heads=cfg["num_attention_heads"], num_kv_heads=cfg["num_key_value_heads"],
         d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
         mlp=MLP_KINDS[cfg["mlp"]], norm="layernorm", rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["param_dtype"],
+        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["param_dtype"], **extra,
     )
 
 

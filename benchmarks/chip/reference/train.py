@@ -2,16 +2,22 @@
 
 Each step runs the forward pass one layer at a time (keeping only the
 layer inputs), the loss head, and the backward pass one layer at a time
-through ``jax.vjp`` of ``model.layer``; rows go through in blocks small
-enough for the attention scores. The device holds the raw fp32 gradients
-of the checked steps, one layer's parameters and the layer inputs, never
-the whole fp32 training state (master, m and v), which is larger than one
-chip.
+through ``jax.vjp`` of ``model.layer``. Rows go through in blocks, and
+attention within a block of rows in blocks of queries (``model.py``), so
+that one block's attention scores stay under ``model.SCORE_BYTES``.
+
+The raw fp32 gradients of the checked steps live in host memory (NumPy
+arrays), each layer's copied there as soon as its backward pass ends. The
+device holds the layer inputs, one layer's parameters and gradients, the
+global leaves, one block's attention and the loss head: never the whole
+fp32 training state (master, m and v), nor the gradients of a whole step,
+each of which is larger than one chip at a few billion parameters.
 
 Adam is replayed leaf by leaf from the initial weights and the stored
-gradients: ``clip -> m, v -> bias correction -> master -= lr * update``,
-the update rule the configuration states (fp32 state, global-norm
-clipping, no weight decay unless the job sets it).
+gradients, which go to the device for that leaf's replay alone: ``clip ->
+m, v -> bias correction -> master -= lr * update``, the update rule the
+configuration states (fp32 state, global-norm clipping, no weight decay
+unless the job sets it).
 
 ``opt_dtype`` and ``rows`` exist for the control and the planted fault:
 ``opt_dtype=bfloat16`` rounds master, m and v to bfloat16 after each
@@ -31,9 +37,6 @@ import numpy as np
 from reference import model
 from weights import global_leaves, layer_leaves, make_leaf, seed_key
 
-# attention scores of one row block stay under this many bytes
-SCORE_BYTES = 1 << 30
-
 
 @dataclasses.dataclass
 class Readings:
@@ -51,8 +54,7 @@ def leaf_name(leaf, layer) -> str:
 class Reference:
     def __init__(self, cfg: dict, opt: dict, *, opt_dtype=jnp.float32, rows=None,
                  device=None):
-        if cfg["positions"] != "rope_full_head":
-            raise ValueError(f"unsupported positions {cfg['positions']!r}")
+        model.rotary_fraction(cfg)  # refuses positions the reference does not know
         if cfg["num_key_value_heads"] != cfg["num_attention_heads"]:
             raise ValueError("grouped-query attention is not in the reference")
         self.cfg, self.opt, self.rows = cfg, opt, rows
@@ -76,63 +78,93 @@ class Reference:
                 for lf in self.layer}
 
     def run(self, seed: int, batches: list[dict]) -> Readings:
-        cfg, f = self.cfg, self.fns
+        store, clips, losses = self.steps(seed, batches)
+        return self.readings(seed, store, clips, losses)
+
+    def steps(self, seed: int, batches: list[dict]):
+        """The training steps: the stored gradients (host arrays, per leaf,
+        one per step), the clipping factor and the loss of each step."""
         key = self._put(seed_key(seed))
-        n_layers = cfg["num_hidden_layers"]
         names = [leaf_name(lf, None) for lf in self.glob.values()] + [
-            leaf_name(lf, l) for l in range(n_layers) for lf in self.layer]
-        store: dict[str, list] = {n: [] for n in names}
+            leaf_name(lf, l) for l in range(self.cfg["num_hidden_layers"]) for lf in self.layer]
+        store: dict[str, list[np.ndarray]] = {n: [] for n in names}
         clips: list[float] = []
         losses: list[float] = []
         for batch in batches:
-            tokens, labels = batch["tokens"], batch["labels"]
-            if self.rows is not None:
-                tokens, labels = tokens[self.rows], labels[self.rows]
-            n_tok = tokens.size
-            rb = max(1, min(tokens.shape[0], SCORE_BYTES // (
-                4 * cfg["num_attention_heads"] * tokens.shape[1] ** 2)))
-            blocks = [slice(i, i + rb) for i in range(0, tokens.shape[0], rb)]
-            g = {n: self._params(key, lf, None, store[n], clips) for n, lf in self.glob.items()}
-            emb = g["embed.tok"]
-            w_out = emb.T if cfg["tie_word_embeddings"] else g["head.w"]
-            xs = [[f.embed(emb, self._put(tokens[b])) for b in blocks]]
-            for l in range(n_layers):
-                p = self._layer_params(key, l, store, clips)
-                xs.append([f.layer_fwd(p, x) for x in xs[-1]])
-            fin = {k: g[k] for k in ("final_norm.scale", "final_norm.bias")}
-            total, grads, dxs = 0.0, {}, []
-            for b, x in zip(blocks, xs[-1]):
-                ls, (d_fin, d_w, dx) = f.head(fin, w_out, x, self._put(labels[b]),
-                                              jnp.float32(n_tok))
-                total += float(ls)
-                _acc(grads, d_fin)
-                _acc(grads, {"w_out": d_w})
-                dxs.append(dx)
-            sumsq = 0.0
-            for l in reversed(range(n_layers)):
-                p = self._layer_params(key, l, store, clips)
-                dp = {}
-                for i, x in enumerate(xs[l]):
-                    d, dxs[i] = f.layer_bwd(p, x, dxs[i])
-                    _acc(dp, d)
-                for k, v in dp.items():
-                    sumsq += float(f.sumsq(v))
-                    store[f"layers.{l}.{k}"].append(v)
-                xs[l + 1] = None
-            d_emb = sum(f.embed_bwd(dx, self._put(tokens[b]), cfg["vocab_size"])
-                        for b, dx in zip(blocks, dxs))
-            if cfg["tie_word_embeddings"]:
-                d_emb = d_emb + grads.pop("w_out").T
-            else:
-                grads["head.w"] = grads.pop("w_out")
-            grads["embed.tok"] = d_emb
-            for k, v in grads.items():
-                sumsq += float(f.sumsq(v))
-                store[k].append(v)
-            losses.append(total / n_tok)
+            loss, sumsq = self._step(key, batch, store, clips)
+            losses.append(loss)
             clips.append(min(1.0, self.opt["grad_clip"] / max(np.sqrt(sumsq), 1e-12)))
+        return store, clips, losses
+
+    def _step(self, key, batch: dict, store: dict, clips: list[float]):
+        """One step: appends each leaf's gradient to ``store``; returns the
+        loss and the gradients' sum of squares. Its device arrays end with
+        it."""
+        cfg, f = self.cfg, self.fns
+        n_layers = cfg["num_hidden_layers"]
+        tokens, labels = batch["tokens"], batch["labels"]
+        if self.rows is not None:
+            tokens, labels = tokens[self.rows], labels[self.rows]
+        n_tok = tokens.size
+        rb = max(1, min(tokens.shape[0], model.SCORE_BYTES // (
+            4 * cfg["num_attention_heads"] * tokens.shape[1] ** 2)))
+        blocks = [slice(i, i + rb) for i in range(0, tokens.shape[0], rb)]
+        g = {n: self._params(key, lf, None, store[n], clips) for n, lf in self.glob.items()}
+        emb = g["embed.tok"]
+        w_out = emb.T if cfg["tie_word_embeddings"] else g["head.w"]
+        xs = [[f.embed(emb, self._put(tokens[b])) for b in blocks]]
+        for l in range(n_layers):
+            p = self._layer_params(key, l, store, clips)
+            xs.append([f.layer_fwd(p, x) for x in xs[-1]])
+        del p
+        fin = {k: g[k] for k in ("final_norm.scale", "final_norm.bias")}
+        total, grads, dxs = 0.0, {}, []
+        for b, x in zip(blocks, xs[-1]):
+            ls, (d_fin, d_w, dx) = f.head(fin, w_out, x, self._put(labels[b]),
+                                          jnp.float32(n_tok))
+            total += float(ls)
+            _acc(grads, d_fin)
+            _acc(grads, {"w_out": d_w})
+            dxs.append(dx)
+        sumsq = 0.0
+        for l in reversed(range(n_layers)):
+            sumsq += self._layer_backward(key, l, store, clips, xs[l], dxs)
+            xs[l + 1] = None
+        d_emb = sum(f.embed_bwd(dx, self._put(tokens[b]), cfg["vocab_size"])
+                    for b, dx in zip(blocks, dxs))
+        if cfg["tie_word_embeddings"]:
+            d_emb = d_emb + grads.pop("w_out").T
+        else:
+            grads["head.w"] = grads.pop("w_out")
+        grads["embed.tok"] = d_emb
+        for k, v in grads.items():
+            sumsq += float(f.sumsq(v))
+            store[k].append(np.asarray(v))
+        return total / n_tok, sumsq
+
+    def _layer_backward(self, key, l: int, store: dict, clips: list[float], xs: list,
+                        dxs: list) -> float:
+        """Layer ``l``'s backward over the row blocks: ``dxs`` become the
+        gradients of the layer's inputs ``xs``; the layer's gradients go to
+        ``store`` on the host. Returns their sum of squares."""
+        f = self.fns
+        p = self._layer_params(key, l, store, clips)
+        dp = {}
+        for i, x in enumerate(xs):
+            d, dxs[i] = f.layer_bwd(p, x, dxs[i])
+            _acc(dp, d)
+        sumsq = 0.0
+        for k, v in dp.items():
+            sumsq += float(f.sumsq(v))
+            store[f"layers.{l}.{k}"].append(np.asarray(v))
+        return sumsq
+
+    def readings(self, seed: int, store: dict, clips: list[float],
+                 losses: list[float]) -> Readings:
+        """Adam replayed over the stored gradients, leaf by leaf."""
+        f, key = self.fns, self._put(seed_key(seed))
         grad_norms, change_norms = {}, {}
-        for l in [None, *range(n_layers)]:
+        for l in [None, *range(self.cfg["num_hidden_layers"])]:
             for lf in (self.glob.values() if l is None else self.layer):
                 n = leaf_name(lf, l)
                 gs = tuple(self._put(x) for x in store[n])
