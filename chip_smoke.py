@@ -165,7 +165,7 @@ def one_chip(seed: int) -> None:
     if plan.n_host < 1:
         fail(f"the searched plan has no host chunk: {plan.describe()}")
     host_b = host_state_bytes(art.state_specs)
-    say(f"host RAM (/proc/meminfo MemTotal) {hw.host_mem_bytes / 1e9:.2f} GB; plan's "
+    say(f"host RAM free (/proc/meminfo MemAvailable) {hw.host_mem_bytes / 1e9:.2f} GB; plan's "
         f"host-placed state {host_b / 1e9:.3f} GB")
 
     res = train(art, cfg, shape, seed)

@@ -27,7 +27,8 @@ JAMBA_1_5_LARGE_398B = ModelConfig(
     mamba2=Mamba2Config(d_state=128, head_dim=64, expand=2),
 )
 
-# [hf:stabilityai/stablelm-2-1_6b; unverified] — MHA (kv == heads).
+# [hf:stabilityai/stablelm-3b-4e1t config.json] — MHA (kv == heads), partial
+# rotary over the first 25% of each head, LayerNorm eps 1e-5, untied head.
 STABLELM_3B = ModelConfig(
     name="stablelm-3b",
     family="dense",
@@ -39,6 +40,8 @@ STABLELM_3B = ModelConfig(
     vocab_size=50304,
     mlp="swiglu",
     norm="layernorm",
+    rotary_fraction=0.25,
+    norm_eps=1e-5,
 )
 
 # [arXiv:2407.21783; unverified] — GQA kv=8, 128k vocab.

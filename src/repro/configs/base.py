@@ -59,6 +59,10 @@ class ModelConfig:
     mlp: MlpKind = "swiglu"
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
     rope_theta: float = 10_000.0
+    # Share of each head that rotary embeddings rotate (the first
+    # int(rotary_fraction * head_dim) dims; the rest pass through).
+    rotary_fraction: float = 1.0
+    norm_eps: float = 1e-6
     # Sliding-window attention size; 0 = full attention.
     sliding_window: int = 0
     # Per-layer mixer pattern, tiled over layers (e.g. Jamba 1 attn : 7 mamba).

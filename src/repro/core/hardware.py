@@ -121,17 +121,20 @@ HARDWARE = {h.name: h for h in (TPU_V5E, RTX_3090, A100_80G)}
 DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
 
 
-def host_memory_bytes() -> float:
-    """This host's physical memory (``MemTotal`` of /proc/meminfo), in bytes."""
-    with open("/proc/meminfo") as f:
+def host_memory_bytes(meminfo: str = "/proc/meminfo") -> float:
+    """The host memory free for offload now, in bytes: ``MemAvailable`` of
+    /proc/meminfo. Not ``MemTotal``: by the time a plan is made, the
+    accelerator's runtime already holds part of it (13.9 GB on a one-chip
+    TPU v5e host)."""
+    with open(meminfo) as f:
         for line in f:
-            if line.startswith("MemTotal:"):
+            if line.startswith("MemAvailable:"):
                 return float(line.split()[1]) * 1024
-    raise RuntimeError("no MemTotal line in /proc/meminfo")
+    raise RuntimeError(f"no MemAvailable line in {meminfo}")
 
 
 def hardware_for_device(device) -> HardwareSpec:
-    """The spec of ``device``'s accelerator, with this host's memory.
+    """The spec of ``device``'s accelerator, with this host's free memory.
 
     An unknown ``device_kind`` is an error: planning a chip against another
     chip's constants would produce a plan that does not fit or wastes it.

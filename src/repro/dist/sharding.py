@@ -105,6 +105,19 @@ def sharding_for(
     return NamedSharding(mesh, spec)
 
 
+def host_layer_sliceable(d) -> bool:
+    """Whether one layer of a stacked leaf (a ``ParamDef`` or an array, its
+    layer axis leading) can move between host memory and the device on
+    its own. The layer axis must lie outside the TPU's two minor, tiled
+    dims, so the leaf needs rank 3 or more: one layer of a ``[L, d]`` leaf
+    cuts across the (8, 128) tile's sublanes, a DMA the compiler refuses
+    ("Sublane slicing size not multiple of chunk sublane size") or that
+    halts the core ("The DMA stride is invalid"). Host runs keep such
+    parameters in HBM (``train/step_builder.py``), and the streamed Adam
+    update leaves their states whole (``optim/adam.streams``)."""
+    return len(d.shape) >= 3
+
+
 def gather_sharding(d: ParamDef, mesh, *, dp_only: bool = False) -> NamedSharding:
     """Point-of-use layout: ZeRO axes gathered (replicated), TP kept, in
     device memory — the target of the per-chunk all-gather."""

@@ -155,8 +155,8 @@ def _decode_attention(ap: dict, h: jax.Array, cache: dict, pos: jax.Array,
     k = (h @ ap["wk"]).reshape(b, 1, cfg.num_kv_heads, hd)
     v = (h @ ap["wv"]).reshape(b, 1, cfg.num_kv_heads, hd)
     positions = rope_positions(pos)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
+    q = L.apply_rope(q, positions, cfg.rope_theta, cfg.rotary_fraction)
+    k = L.apply_rope(k, positions, cfg.rope_theta, cfg.rotary_fraction)
     kv_io = kv_io or RESIDENT_KV
     attend = getattr(kv_io, "attend", None)
     if attend is not None:
@@ -202,7 +202,7 @@ def decode_position(pparams: dict, x: jax.Array, pcache: dict, pos: jax.Array,
                     cfg: ModelConfig, kv_io=None, active=None):
     """One layer, one token. x: (B,1,D). ``active`` (B,) bool masks cache
     writes for slots not participating in this step (chunked prefill)."""
-    h = L.apply_norm(pparams["norm1"], x, cfg.norm)
+    h = L.apply_norm(pparams["norm1"], x, cfg.norm, cfg.norm_eps)
     new_cache = dict(pcache)
     if "attn" in pparams:
         keys = (kv_io or RESIDENT_KV).entry_keys
@@ -221,16 +221,16 @@ def decode_position(pparams: dict, x: jax.Array, pcache: dict, pos: jax.Array,
         new_cache.update({"conv": conv, "ssm": ssm})
     x = x + mix
     if "xattn" in pparams:
-        hx = L.apply_norm(pparams["norm_x"], x, cfg.norm)
+        hx = L.apply_norm(pparams["norm_x"], x, cfg.norm, cfg.norm_eps)
         x = x + _decode_cross_attention(pparams["xattn"], hx, pcache["xk"], pcache["xv"], cfg)
     if "moe" in pparams:
         from repro.models.moe import apply_moe
 
-        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm)
+        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm, cfg.norm_eps)
         out, _ = apply_moe(pparams["moe"], h2, cfg)
         x = x + out
     elif "mlp" in pparams:
-        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm)
+        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm, cfg.norm_eps)
         x = x + L.apply_mlp(pparams["mlp"], h2, cfg.mlp)
     return shard_act(x, "bsd"), new_cache
 

@@ -87,10 +87,10 @@ def norm_defs(d: int, kind: str) -> dict:
     }
 
 
-def apply_norm(params: dict, x: jax.Array, kind: str) -> jax.Array:
+def apply_norm(params: dict, x: jax.Array, kind: str, eps: float = 1e-6) -> jax.Array:
     if kind == "rmsnorm":
-        return rmsnorm(x, params["scale"])
-    return layernorm(x, params["scale"], params["bias"])
+        return rmsnorm(x, params["scale"], eps)
+    return layernorm(x, params["scale"], params["bias"], eps)
 
 
 # ---------------------------------------------------------------------------
@@ -101,9 +101,18 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta**exponents)  # (hd/2,)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: (..., S) int32."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               fraction: float = 1.0) -> jax.Array:
+    """x: (..., S, H, hd); positions: (..., S) int32.
+
+    Rotates the first ``int(fraction * hd)`` dims of each head (NeoX halves
+    within them, frequencies over those dims, as StableLM's partial rotary)
+    and passes the rest through unchanged."""
     hd = x.shape[-1]
+    rd = int(fraction * hd)
+    if rd < hd:
+        rot = apply_rope(x[..., :rd], positions, theta)
+        return jnp.concatenate([rot, x[..., rd:]], axis=-1)
     freqs = rope_frequencies(hd, theta)  # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., :, None, :]  # (..., S, 1, hd/2)
@@ -319,8 +328,8 @@ def attention_block(
     v = (x @ params["wv"]).reshape(b, s, cfg.num_kv_heads, hd)
     if positions is None:
         positions = jnp.arange(s)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_fraction)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_fraction)
     fn = blockwise_attention if impl == "blockwise" else naive_attention
     kwargs = dict(causal=True, window=cfg.sliding_window)
     if impl == "blockwise":

@@ -282,12 +282,14 @@ def gather_weights(params, specs=None):
     # it XLA commutes slice-of-stack with all-gather and hoists the gather of
     # the whole stacked run out of the loop — materializing every layer's
     # weights at once (the exact pattern chunk-wise gathering must avoid).
-    params = jax.lax.optimization_barrier(params)
-    return jax.tree.map(
-        lambda w, s: checkpoint_name(w if s is None else jax.device_put(w, s), GATHERED_W),
-        params,
-        specs,
-    )
+    # The scope names the fetch in forward and in a backward re-fetch.
+    with jax.named_scope("param_fetch"):
+        params = jax.lax.optimization_barrier(params)
+        return jax.tree.map(
+            lambda w, s: checkpoint_name(w if s is None else jax.device_put(w, s), GATHERED_W),
+            params,
+            specs,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +313,7 @@ def apply_position(
     modes route them through the quantize-on-save seam (``save_act``)."""
     aux = jnp.zeros((), jnp.float32)
     x = shard_act(x, "enter")  # SP: gather seq-sharded boundary for compute
-    h = L.apply_norm(pparams["norm1"], x, cfg.norm)
+    h = L.apply_norm(pparams["norm1"], x, cfg.norm, cfg.norm_eps)
     h = save_act(h, act_mode)
     if "attn" in pparams:
         mix = L.attention_block(pparams["attn"], h, cfg, positions=positions, impl=attn_impl)
@@ -319,15 +321,15 @@ def apply_position(
         mix = M2.apply_mamba2(pparams["mamba"], h, cfg)
     x = x + save_act(mix, act_mode)
     if memory is not None and "xattn" in pparams:
-        hx = L.apply_norm(pparams["norm_x"], x, cfg.norm)
+        hx = L.apply_norm(pparams["norm_x"], x, cfg.norm, cfg.norm_eps)
         x = x + save_act(L.cross_attention_block(pparams["xattn"], hx, memory, cfg), act_mode)
     if "moe" in pparams:
-        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm)
+        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm, cfg.norm_eps)
         out, moe_aux = MOE.apply_moe(pparams["moe"], h2, cfg)
         x = x + save_act(out, act_mode)
         aux = aux + moe_aux
     elif "mlp" in pparams:
-        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm)
+        h2 = L.apply_norm(pparams["norm2"], x, cfg.norm, cfg.norm_eps)
         x = x + save_act(L.apply_mlp(pparams["mlp"], h2, cfg.mlp), act_mode)
     return shard_act(x), aux
 
@@ -374,7 +376,7 @@ def apply_superblock(
     return x, aux
 
 
-REMAT_POLICIES: dict[tuple[str, bool, bool], Any] = {}
+REMAT_POLICIES: dict[tuple[str, bool, bool, bool], Any] = {}
 
 
 def _is_lazy_gather_eqn(prim, params) -> bool:
@@ -388,44 +390,49 @@ def _is_lazy_gather_eqn(prim, params) -> bool:
         e.primitive.name == "all_gather" for e in eqns)
 
 
-def _save_acts_not_lazy_gathers():
+def _save_acts_not_fetches(host: bool = False):
     """save_anything_except_these_names(GATHERED_W), plus: never save the
-    *raw* all-gather output feeding the name. Without the second clause the
+    *raw* fetch output feeding the name. Without the second clause the
     name exclusion is defeated — the named value is an identity of the
-    unnamed gather output, so partial-eval happily saves the unnamed ancestor
-    and the "re-gather in BWD" semantics silently becomes "buffered". By the
-    time the policy runs the gather custom_vjp has been inlined, so the
-    exclusion matches the ``all_gather`` primitive itself (the only
-    all-gathers inside a lazy run's remat region are the lazy weight
-    gathers; activation sharding is identity under manual sync) — with the
-    custom_vjp-eqn matcher kept for jax versions that keep the call
-    un-inlined."""
+    unnamed fetch output, so partial-eval happily saves the unnamed ancestor
+    and the "re-gather in BWD" semantics silently becomes "buffered".
+
+    A lazy run's fetch is its all-gather: by the time the policy runs the
+    gather custom_vjp has been inlined, so the exclusion matches the
+    ``all_gather`` primitive itself (the only all-gathers inside a lazy
+    run's remat region are the lazy weight gathers; activation sharding is
+    identity under manual sync) — with the custom_vjp-eqn matcher kept for
+    jax versions that keep the call un-inlined. A host-resident run's
+    (``host``) is ``gather_weights``' ``optimization_barrier`` and
+    ``device_put`` into HBM: saved raw, they would keep every layer's
+    weights in device memory from forward to backward."""
     base = jax.checkpoint_policies.save_anything_except_these_names(GATHERED_W)
+    fetches = ("all_gather", "optimization_barrier", "device_put") if host else ("all_gather",)
 
     def policy(prim, *avals, **params):
-        if prim.name == "all_gather" or _is_lazy_gather_eqn(prim, params):
+        if prim.name in fetches or _is_lazy_gather_eqn(prim, params):
             return False
         return base(prim, *avals, **params)
 
     return policy
 
 
-def _remat_policy(act_policy: str, buffered: bool, lazy: bool = False):
+def _remat_policy(act_policy: str, buffered: bool, lazy: bool = False, host: bool = False):
     """Map (activation policy, weights-buffered?) to a jax.checkpoint policy.
 
     ``lazy``: the run gathers weights through ``gather_param_lazy`` (manual
-    ZeRO-3) — the unbuffered keep-activations policy must then also exclude
-    the gather custom_vjp's raw output from saving (see
-    ``_save_acts_not_lazy_gathers``)."""
-    key = (act_policy, buffered, lazy)
+    ZeRO-3); ``host``: its weights live in host memory. The unbuffered
+    keep-activations policy must then also exclude the fetch's raw output
+    from saving (see ``_save_acts_not_fetches``)."""
+    key = (act_policy, buffered, lazy, host)
     if key in REMAT_POLICIES:
         return REMAT_POLICIES[key]
     cp = jax.checkpoint_policies
     if act_policy == "none":
         if buffered:
             pol = cp.everything_saveable
-        elif lazy:
-            pol = _save_acts_not_lazy_gathers()
+        elif lazy or host:
+            pol = _save_acts_not_fetches(host)
         else:
             pol = cp.save_anything_except_these_names(GATHERED_W)
     elif act_policy == "checkpoint":
@@ -459,6 +466,7 @@ class Run:
     act_policy: str = "none"  # none | checkpoint | swap | compress8 | compress16
     buffered: bool = True  # gathered weights saved fwd->bwd?
     persistent: bool = False  # params replicated over zero axes (no gather)
+    host: bool = False  # params in host memory: the gather fetches over the host link
     gather_specs: Any = None  # per-repeat pytree of NamedSharding (ZeRO dropped)
     ckpt_group: int = 1  # remat region size in superblock repeats (sqrt(n) trade)
     # manual ZeRO-3 lazy gather: hook (per-repeat params, per-repeat ef) ->
@@ -493,7 +501,7 @@ def apply_runs(
         pol = (
             None
             if run.act_policy == "none" and run.buffered
-            else _remat_policy(run.act_policy, run.buffered, lazy)
+            else _remat_policy(run.act_policy, run.buffered, lazy, run.host)
         )
         g = run.ckpt_group if run.act_policy == "checkpoint" else 1
         g = max(1, min(g, run.n_repeats))
@@ -539,7 +547,7 @@ def apply_runs(
                 return (x, aux)
 
             region_ck = jax.checkpoint(
-                region, policy=_remat_policy(run.act_policy, run.buffered, lazy))
+                region, policy=_remat_policy(run.act_policy, run.buffered, lazy, run.host))
 
             def body(carry, gsl, _f=region_ck):
                 return _f(carry, gsl), None
@@ -628,7 +636,7 @@ def embed_tokens(params: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array
 
 
 def lm_head(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    x = L.apply_norm(params["final_norm"], x, cfg.norm)
+    x = L.apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     w = params["embed"]["tok"].T if cfg.tie_embeddings else params["head"]["w"]
     return shard_act(x @ w, "logits")
 
@@ -641,24 +649,24 @@ def encode(params: dict, frames: jax.Array, cfg: ModelConfig, gather_specs=None)
     def body(carry, bp):
         x = carry
         pp = gather_weights(bp, gather_specs)
-        h = L.apply_norm(pp["norm1"], x, cfg.norm)
+        h = L.apply_norm(pp["norm1"], x, cfg.norm, cfg.norm_eps)
         b, s, d = h.shape
         hd = cfg.resolved_head_dim
         q = (h @ pp["attn"]["wq"]).reshape(b, s, cfg.num_heads, hd)
         k = (h @ pp["attn"]["wk"]).reshape(b, s, cfg.num_kv_heads, hd)
         v = (h @ pp["attn"]["wv"]).reshape(b, s, cfg.num_kv_heads, hd)
         pos = jnp.arange(s)
-        q = L.apply_rope(q, pos, cfg.rope_theta)
-        k = L.apply_rope(k, pos, cfg.rope_theta)
+        q = L.apply_rope(q, pos, cfg.rope_theta, cfg.rotary_fraction)
+        k = L.apply_rope(k, pos, cfg.rope_theta, cfg.rotary_fraction)
         o = L.blockwise_attention(q, k, v, causal=False, block_kv=min(1024, s))
         x = x + checkpoint_name(o.reshape(b, s, -1) @ pp["attn"]["wo"], ACT)
-        h2 = L.apply_norm(pp["norm2"], x, cfg.norm)
+        h2 = L.apply_norm(pp["norm2"], x, cfg.norm, cfg.norm_eps)
         x = x + checkpoint_name(L.apply_mlp(pp["mlp"], h2, cfg.mlp), ACT)
         return shard_act(x), None
 
     body_ck = jax.checkpoint(body, policy=_remat_policy("checkpoint", True))
     x, _ = jax.lax.scan(body_ck, x, enc["blocks"])
-    return L.apply_norm(enc["final_norm"], x, cfg.norm)
+    return L.apply_norm(enc["final_norm"], x, cfg.norm, cfg.norm_eps)
 
 
 def forward(

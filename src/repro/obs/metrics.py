@@ -241,6 +241,7 @@ DOCUMENTED_METRICS = (
     # train/step_builder.py
     "offload.bytes_per_step",
     "offload.streamed_bytes_per_step",
+    "offload.param_fetch_bytes_per_step",
     # serve/engine.py + serve/scheduler.py
     "serve.ticks",
     "serve.generated_tokens",

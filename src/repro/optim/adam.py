@@ -15,6 +15,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.dist.sharding import host_layer_sliceable
+
 
 @dataclasses.dataclass(frozen=True)
 class AdamConfig:
@@ -116,11 +118,11 @@ def _leading_unsharded(sharding) -> bool:
 
 def streams(p, host: HostLeaf | None) -> bool:
     """Whether a leaf's update streams layer by layer: a host-offloaded
-    stacked leaf whose layer axis leads, is not sharded, and is neither of
-    the two minor (tiled) dims. A per-layer slice of a 2-D stacked leaf
-    ``[L, d]`` would cut across the TPU's (8, 128) tile on its second-minor
-    dim, a DMA the chip refuses."""
-    return (host is not None and host.stacked and p.ndim >= 3 and p.shape[0] >= 2
+    stacked leaf whose layer axis leads, is not sharded, and can be sliced
+    one layer at a time over the host link (``dist.sharding.host_layer_sliceable``:
+    not a 2-D ``[L, d]`` leaf)."""
+    return (host is not None and host.stacked and host_layer_sliceable(p)
+            and p.shape[0] >= 2
             and all(map(_leading_unsharded, (host.param, host.host, host.device))))
 
 

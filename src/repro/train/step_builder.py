@@ -168,6 +168,33 @@ def record_offload_inventory(state_specs, microbatch: int, host_plan: list) -> N
     reg.gauge("offload.streamed_bytes_per_step", dir="writeback").set(back)
 
 
+def record_param_fetch(param_specs, microbatch: int, runs: list[RunLayout]) -> None:
+    """Record the bf16 parameter bytes one step fetches from host memory,
+    per device, as the gauge ``offload.param_fetch_bytes_per_step``. Every
+    microbatch's forward fetches each host-placed parameter: a run's one
+    layer at a time (``models.model.gather_weights``, scope
+    ``param_fetch``), the loss head's whole. The backward of a run that
+    does not keep its fetched weights (``RunLayout.buffered`` False, the
+    plan's ``n_buffer``) fetches its layers again. Counted from the specs'
+    memory kinds and per-device shard shapes; a no-op without an installed
+    telemetry handle."""
+    from repro import obs
+
+    total = 0
+    for path, s in jax.tree_util.tree_flatten_with_path(param_specs)[0]:
+        if s.sharding.memory_kind in (None, "device"):
+            continue
+        again = path[0].key == "runs" and not runs[path[1].idx].buffered
+        total += (math.prod(s.sharding.shard_shape(s.shape)) * s.dtype.itemsize
+                  * microbatch * (2 if again else 1))
+    obs.current_telemetry().registry.gauge("offload.param_fetch_bytes_per_step").set(total)
+
+
+def _stacked(d: ParamDef) -> bool:
+    """A run's stacked block leaf: its leading axis is the layer axis."""
+    return bool(d.axes) and d.axes[0] == LAYER
+
+
 def _opt_placement(placement: str, plan: MemoryPlan) -> str:
     """Optimizer-state placement for a chunk placement."""
     if placement == "persist":
@@ -202,9 +229,22 @@ def build_train_step(
     embed_chunk = plan.chunk_placement(0)
     dp = plan.dp_only
 
-    def param_place(pl: str) -> str:
-        # ZeRO-Offload split: bf16 params stay in HBM; only opt states go host
-        return "hbm" if (pl == "host" and not plan.host_params) else pl
+    def param_place(pl: str, d: ParamDef | None = None) -> str:
+        """A parameter's placement in a chunk placed ``pl``. ZeRO-Offload
+        split: bf16 params stay in HBM; only opt states go host. A stacked
+        leaf the host link cannot fetch one layer at a time (``[L, d]``
+        norms) stays in HBM too."""
+        if pl != "host":
+            return pl
+        if not plan.host_params or (d is not None and _stacked(d)
+                                    and not SH.host_layer_sliceable(d)):
+            return "hbm"
+        return pl
+
+    def place_tree(subtree_defs, pl: str):
+        return jax.tree.map(
+            lambda d: SH.sharding_for(d, mesh, placement=param_place(pl, d), dp_only=dp),
+            subtree_defs, is_leaf=lambda x: isinstance(x, ParamDef))
 
     head_pchunk = param_place(head_chunk)
     embed_pchunk = param_place(embed_chunk)
@@ -221,17 +261,15 @@ def build_train_step(
         p_defs["encoder"] = defs["encoder"]
 
     p_shard: dict[str, Any] = {
-        "embed": SH.tree_shardings(defs["embed"], mesh, placement=embed_pchunk, dp_only=dp),
-        "final_norm": SH.tree_shardings(defs["final_norm"], mesh, placement=head_pchunk, dp_only=dp),
-        "runs": [
-            SH.tree_shardings(p_defs["runs"][i], mesh, placement=param_place(r.placement), dp_only=dp)
-            for i, r in enumerate(runs_layout)
-        ],
+        "embed": place_tree(defs["embed"], embed_chunk),
+        "final_norm": place_tree(defs["final_norm"], head_chunk),
+        "runs": [place_tree(p_defs["runs"][i], r.placement)
+                 for i, r in enumerate(runs_layout)],
     }
     if "head" in defs:
-        p_shard["head"] = SH.tree_shardings(defs["head"], mesh, placement=head_pchunk, dp_only=dp)
+        p_shard["head"] = place_tree(defs["head"], head_chunk)
     if "encoder" in defs:
-        p_shard["encoder"] = SH.tree_shardings(defs["encoder"], mesh, placement=embed_pchunk, dp_only=dp)
+        p_shard["encoder"] = place_tree(defs["encoder"], embed_chunk)
 
     # --- optimizer state shardings (fp32 master/m/v) ------------------------
     def opt_tree(fn_placement):
@@ -270,10 +308,10 @@ def build_train_step(
             return None
         df = fp32_def(d)
         return OPT.HostLeaf(
-            SH.sharding_for(d, mesh, placement=param_place("host"), dp_only=dp),
+            SH.sharding_for(d, mesh, placement=param_place("host", d), dp_only=dp),
             SH.sharding_for(df, mesh, placement="host", dp_only=dp),
             SH.sharding_for(df, mesh, placement="hbm", dp_only=dp),
-            stacked=bool(d.axes) and d.axes[0] == LAYER,
+            stacked=_stacked(d),
         )
 
     host_plan_flat = [
@@ -382,6 +420,7 @@ def build_train_step(
                 act_policy=r.act_policy,
                 buffered=True if full else r.buffered,
                 persistent=True if full else r.placement == "persist",
+                host=not full and param_place(r.placement) == "host",
                 gather_specs=None if full else gather_specs[i],
                 ckpt_group=plan.ckpt_group,
             )
@@ -431,7 +470,7 @@ def build_train_step(
             from repro.models.layers import apply_norm
 
             h = M.shard_act(h, "enter")  # SP: back to batch-only for the CE scan
-            h = apply_norm(fparams["final_norm"], h, cfg.norm)
+            h = apply_norm(fparams["final_norm"], h, cfg.norm, cfg.norm_eps)
             w = fparams["embed"]["tok"].T if cfg.tie_embeddings else fparams["head"]["w"]
             loss = chunked_cross_entropy(
                 h, w, batch["labels"], ce_chunk=ce_chunk, w_acc_sharding=w_acc
@@ -521,7 +560,7 @@ def build_train_step(
             from repro.models.layers import apply_norm
 
             h = M.shard_act(h, "enter")
-            h = apply_norm(fparams["final_norm"], h, cfg.norm)
+            h = apply_norm(fparams["final_norm"], h, cfg.norm, cfg.norm_eps)
             w = fparams["embed"]["tok"].T if cfg.tie_embeddings else fparams["head"]["w"]
             loss = chunked_cross_entropy(
                 h, w, batch["labels"], ce_chunk=ce_chunk, w_acc_sharding=None
@@ -559,6 +598,7 @@ def build_train_step(
     # runtime
     SYNC.record_sync_inventory(strategy, state_specs["params"], plan.microbatch)
     record_offload_inventory(state_specs, plan.microbatch, host_plan_flat)
+    record_param_fetch(state_specs["params"], plan.microbatch, runs_layout)
     compress = plan.grad_compress
     ef_layout = strategy.ef_state(o_defs_one, g_shard)
     if ef_layout is not None:
@@ -933,7 +973,7 @@ def build_prefill_step(cfg: ModelConfig, plan: MemoryPlan, mesh, shape: ShapeCon
         h, _ = M.forward(params, batch, cfg, runs=runs)
         from repro.models.layers import apply_norm
 
-        h = apply_norm(params["final_norm"], h[:, -1:], cfg.norm)
+        h = apply_norm(params["final_norm"], h[:, -1:], cfg.norm, cfg.norm_eps)
         w = params["embed"]["tok"].T if cfg.tie_embeddings else params["head"]["w"]
         return (h @ w)[:, 0]  # (B, V) next-token logits
 

@@ -166,7 +166,7 @@ def test_xla_loss_parity_compressed_vs_exact(pols):
 @needs_multi_device
 def test_manual_zero3_loss_parity_compressed_vs_exact():
     """Same parity on the manual zero3 lazy-gather path — the compress
-    policy must compose with _save_acts_not_lazy_gathers (save_only keeps
+    policy must compose with _save_acts_not_fetches (save_only keeps
     int8 payloads, re-gathers weights, never quantizes a gather)."""
     mesh = dp_mesh()
     exact = run_steps(MemoryPlan(n_chunks=4, n_blocks=2), mesh)

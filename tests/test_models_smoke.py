@@ -116,3 +116,73 @@ def test_mamba_cache_is_constant_size():
     s1 = KV.cache_specs(cfg, B, 64)
     s2 = KV.cache_specs(cfg, B, 4096)
     assert jax.tree.map(lambda a: a.shape, s1) == jax.tree.map(lambda a: a.shape, s2)
+
+
+# ---------------------------------------------------------------------------
+# partial rotary embeddings and the norm's eps (StableLM-3B-4E1T)
+# ---------------------------------------------------------------------------
+def _rope_before(x, positions, theta):
+    """apply_rope as it was before ``fraction``: the whole head rotates."""
+    from repro.models.layers import rope_frequencies
+
+    freqs = rope_frequencies(x.shape[-1], theta)
+    angles = positions[..., :, None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(angles)[..., :, None, :], jnp.sin(angles)[..., :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_full_rotary_is_bitwise_the_rope_before(dtype):
+    from repro.models.layers import apply_rope
+
+    x = jax.random.normal(KEY, (2, 16, 4, 32), dtype)
+    pos = jnp.arange(16)
+    fn = jax.jit(lambda x: apply_rope(x, pos, 10000.0, 1.0))
+    assert jnp.array_equal(fn(x), jax.jit(lambda x: _rope_before(x, pos, 10000.0))(x))
+
+
+@pytest.mark.parametrize("head_dim", [80, 32])
+def test_partial_rotary_matches_the_reference(head_dim):
+    """A quarter of each head rotates, as the benchmark's float32 reference
+    (``benchmarks/chip/reference/model.rope``) and StableLM rotate it."""
+    import sys
+    from pathlib import Path
+
+    import numpy as np
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks" / "chip"))
+    from reference.model import rope
+
+    from repro.models.layers import apply_rope
+
+    x = jax.random.normal(KEY, (2, 64, 4, head_dim), jnp.float32)
+    got = apply_rope(x, jnp.arange(64), 10000.0, 0.25)
+    np.testing.assert_allclose(got, rope(x, 10000.0, 0.25), rtol=1e-5, atol=1e-5)
+    rd = int(0.25 * head_dim)
+    assert jnp.array_equal(got[..., rd:], x[..., rd:])  # the rest passes through
+
+
+def test_norm_eps_reaches_layernorm(monkeypatch):
+    """Every LayerNorm of the forward, the final one included, takes the
+    configuration's eps."""
+    from repro.models import layers as L
+
+    cfg = reduced(ARCHS["stablelm-3b"])
+    assert (cfg.rotary_fraction, cfg.norm_eps, cfg.norm) == (0.25, 1e-5, "layernorm")
+    seen = []
+    layernorm = L.layernorm
+
+    def spy(x, scale, bias, eps=1e-6):
+        seen.append(eps)
+        return layernorm(x, scale, bias, eps)
+
+    monkeypatch.setattr(L, "layernorm", spy)
+    params = M.init_params(cfg, KEY)
+    h, _ = M.forward(params, make_batch(cfg), cfg)
+    M.lm_head(params, h, cfg)
+    assert seen and set(seen) == {1e-5}
+    # and the eps changes the result: scale-1 rows of tiny variance
+    x = jnp.full((1, 4, 8), 1.0) + 1e-3 * jnp.arange(8.0)
+    ones, zeros = jnp.ones(8), jnp.zeros(8)
+    assert not jnp.allclose(layernorm(x, ones, zeros, 1e-5), layernorm(x, ones, zeros, 1e-6))

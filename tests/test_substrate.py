@@ -251,25 +251,30 @@ def test_streamed_host_update_bitwise_equals_whole_leaf(n_layers):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def _three_layer_setup():
+def _three_layer_setup(arch: str = "llama3-405b"):
     from repro.core import build_workload
     from repro.core.hardware import LOCAL_CPU_HW, MeshSpec
 
-    cfg = reduced(ARCHS["llama3-405b"], num_layers=3, d_model=64, d_ff=128,
+    cfg = reduced(ARCHS[arch], num_layers=3, d_model=64, d_ff=128,
                   vocab_size=256, num_heads=2, num_kv_heads=2, head_dim=32)
     shape = ShapeConfig("host_stream", 32, 2, "train")
     w = build_workload(cfg, shape, MeshSpec((1, 1), ("data", "model")), LOCAL_CPU_HW)
     return cfg, shape, w
 
 
-@pytest.mark.parametrize("host_params", [True, False], ids=["params_on_host", "params_in_hbm"])
-def test_all_host_step_equals_all_device_step(host_params):
+@pytest.mark.parametrize("host_params,arch", [
+    pytest.param(True, "llama3-405b", id="params_on_host"),
+    pytest.param(False, "llama3-405b", id="params_in_hbm"),
+    # partial rotary, LayerNorm with eps 1e-5, SwiGLU, untied head
+    pytest.param(True, "stablelm-3b", id="partial_rotary_params_on_host"),
+])
+def test_all_host_step_equals_all_device_step(host_params, arch):
     """One train step with every chunk's optimizer state on the host (the
     stacked block leaves streamed layer by layer) gives the same bits as
     the same step with nothing on the host."""
     from repro import obs
 
-    cfg, shape, w = _three_layer_setup()
+    cfg, shape, w = _three_layer_setup(arch)
     mesh = local_mesh()
     tel = obs.Telemetry(trace=False)
     with obs.use_telemetry(tel):
@@ -285,6 +290,48 @@ def test_all_host_step_equals_all_device_step(host_params):
     assert float(host_metrics["loss"]) == float(dev_metrics["loss"])
     for a, b in zip(jax.tree.leaves(host_out), jax.tree.leaves(dev_out)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_host_runs_keep_two_dim_layer_leaves_in_hbm():
+    """Under a plan whose parameters live on the host, a run's stacked
+    ``[L, d]`` leaves (the LayerNorm scales and biases) stay in HBM, since
+    one layer of them cannot cross the host link alone; the rank-3 leaves
+    and every optimizer state stay on the host, and only the rank-3 leaves
+    stream their update."""
+    from repro.dist.sharding import host_layer_sliceable
+    from repro.optim.adam import HostLeaf, streams
+
+    cfg, shape, w = _three_layer_setup("stablelm-3b")
+    art = build_train_step(cfg, MemoryPlan(w.n_chunks, w.n_blocks, n_host=w.n_chunks),
+                           local_mesh(), shape)
+
+    def on_host(s):
+        return s.sharding.memory_kind not in (None, "device")
+
+    run = art.state_specs["params"]["runs"][0]["pos0"]
+    for path, s in jax.tree_util.tree_flatten_with_path(run)[0]:
+        assert on_host(s) == (s.ndim >= 3) == host_layer_sliceable(s), (path, s)
+    assert not on_host(run["norm1"]["scale"]) and on_host(run["attn"]["wq"])
+    # outside the stack, whole leaves: the final norm and the head stay on the host
+    assert on_host(art.state_specs["params"]["final_norm"]["scale"])
+    assert on_host(art.state_specs["params"]["head"]["w"])
+    assert all(map(on_host, jax.tree.leaves(art.state_specs["opt"]["master"])))
+    for name, leaf, want in (("norm1", run["norm1"]["scale"], False),
+                             ("attn", run["attn"]["wq"], True)):
+        sh = leaf.sharding
+        assert streams(leaf, HostLeaf(sh, sh, sh, stacked=True)) == want, name
+
+
+def test_host_memory_is_what_the_host_has_free(tmp_path):
+    from repro.core.hardware import host_memory_bytes
+
+    meminfo = tmp_path / "meminfo"
+    meminfo.write_text("MemTotal:       47185920 kB\nMemFree:        30000000 kB\n"
+                       "MemAvailable:   32505856 kB\n")
+    assert host_memory_bytes(str(meminfo)) == 32505856 * 1024
+    meminfo.write_text("MemTotal:       47185920 kB\n")
+    with pytest.raises(RuntimeError, match="MemAvailable"):
+        host_memory_bytes(str(meminfo))
 
 
 def test_search_offers_no_gradient_compression_on_one_device():

@@ -115,3 +115,40 @@ def test_streamed_host_adam_compiles_in_layer_slices(one_chip):
 
     assert temp_bytes(stacked=True) < 3 * 4 * layer_bytes
     assert temp_bytes(stacked=False) >= 3 * 18 * layer_bytes
+
+
+@pytest.mark.parametrize("fault", [False, True], ids=["norms_in_hbm", "norms_on_host"])
+def test_host_params_step_compiles_at_stablelm_widths(one_chip, monkeypatch, fault):
+    """A train step at StableLM-3B-4E1T's widths (2 layers, seq 256 x batch
+    2) whose every chunk, bf16 parameters included, lives in host memory
+    compiles: the layer scan fetches each rank-3 weight one layer at a
+    time, and the ``[L, d]`` norm leaves stay in HBM. Put on the host
+    (``fault``), one layer of them is a slice across the tile's sublanes,
+    which the compiler refuses."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs.archs import STABLELM_3B
+    from repro.configs.base import ShapeConfig
+    from repro.core.plan import MemoryPlan
+    from repro.dist import sharding as SH
+    from repro.train.step_builder import build_train_step
+
+    # the chip's program: StepArtifacts.jit keeps host state pinned off the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if fault:
+        monkeypatch.setattr(SH, "host_layer_sliceable", lambda d: True)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1), ("data", "model"))
+    art = build_train_step(dataclasses.replace(STABLELM_3B, num_layers=2),
+                           MemoryPlan(4, 2, n_host=4, microbatch=2, host_params=True),
+                           mesh, ShapeConfig("t", 256, 2, "train"))
+    norm = art.state_specs["params"]["runs"][0]["pos0"]["norm1"]["scale"]
+    assert (norm.sharding.memory_kind == "pinned_host") == fault
+    lowered = art.lower()
+    if fault:
+        with pytest.raises(jax.errors.JaxRuntimeError, match="Sublane slicing"):
+            lowered.compile()
+    else:
+        lowered.compile()
